@@ -62,6 +62,9 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={args.shards}").strip()
 
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     t_start = time.perf_counter()
     report: dict = {"backend": args.backend, "smoke": args.smoke,
                     "full": args.full, "sections": {}}

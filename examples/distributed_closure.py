@@ -20,6 +20,8 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     import jax
     from jax.sharding import Mesh
     from repro.core.distributed import ClosureConfig, DistributedClosure

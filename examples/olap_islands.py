@@ -37,6 +37,8 @@ def build_shop_kg(n_customers=1000, n_sales=4000, n_returns=400, seed=0):
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     engine = HiperfactEngine(EngineConfig.query1())
     engine.insert_facts(build_shop_kg())
 

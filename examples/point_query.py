@@ -63,6 +63,8 @@ def main() -> None:
     ap.add_argument("--hops", type=int, default=12)
     ap.add_argument("--shards", type=int, default=1)
     args = ap.parse_args()
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     import dataclasses
     query = [cond("path", "c0_n0", "to", "?z")]

@@ -12,6 +12,8 @@ from repro.core.conditions import AddAction, cond, term
 
 
 def main() -> None:
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     engine = HiperfactEngine(EngineConfig.infer1())
 
     # -- the paper's running example: derive USD profits ------------------

@@ -80,6 +80,8 @@ def main() -> None:
     ap.add_argument("--entities", type=int, default=400,
                     help="base dataset size (CI smoke uses a small one)")
     args = ap.parse_args()
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     import dataclasses
     # AI = the sorted-mirror index, rebuilt per append — the config whose

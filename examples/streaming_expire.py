@@ -75,6 +75,8 @@ def main() -> None:
     ap.add_argument("--eval-mode", default="delta",
                     choices=["auto", "delta", "full"])
     args = ap.parse_args()
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     import dataclasses
     cfg = dataclasses.replace(EngineConfig.infer1(args.backend),

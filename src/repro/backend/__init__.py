@@ -27,7 +27,7 @@ _CACHE: dict[str, Ops] = {}
 
 
 def fresh_backend(name: str = "numpy",
-                  compress: bool | None = None) -> Ops:
+                  compress: bool | None = None, device=None) -> Ops:
     """A new, uncached ``Ops`` instance.
 
     Shard workers (``EngineConfig(shards=N)``) each get their own
@@ -37,7 +37,9 @@ def fresh_backend(name: str = "numpy",
 
     ``compress`` controls the device backends' compressed resident
     column tier (``None`` defers to ``REPRO_COMPRESS``, default on);
-    the numpy twin is always raw.
+    the numpy twin is always raw.  ``device`` places a device backend's
+    buffers on that jax device (shard workers, one device each); the
+    numpy twin ignores it.
     """
     if name == "numpy":
         return NumpyOps()
@@ -48,7 +50,7 @@ def fresh_backend(name: str = "numpy",
         # interpret mode uses small blocks: it exists to exercise the
         # kernel code path on CPU, not to win benchmarks
         kw = {"block": 256} if mode == "interpret" else {}
-        return JaxOps(mode=mode, compress=compress, **kw)
+        return JaxOps(mode=mode, compress=compress, device=device, **kw)
     raise ValueError(
         f"unknown backend {name!r}; expected one of {BACKENDS}")
 

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import os
 import threading
 import zlib
 from dataclasses import dataclass
@@ -63,6 +64,13 @@ _CID = itertools.count(1)
 _DICT_IDS: dict[tuple, int] = {}
 _DICT_SEQ = itertools.count(1)
 _LOCK = threading.Lock()
+
+
+def compress_default() -> bool:
+    """Whether device columns and exchange lanes ship coded when the
+    caller leaves ``compress`` unset: on, unless ``REPRO_COMPRESS`` is
+    ``0``, ``false`` or ``off``."""
+    return os.environ.get("REPRO_COMPRESS") not in ("0", "false", "off")
 
 
 def _dict_token(values: np.ndarray) -> int:

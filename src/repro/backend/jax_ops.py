@@ -47,21 +47,28 @@ Shape discipline: inputs are padded to power-of-two buckets with sentinel
 keys (``int64 max`` at the tail for sorts, ``int64 min`` on the join's
 right side) so the jit cache stays logarithmic in observed sizes.
 
-Modes: ``auto`` lets the wrappers pick Pallas on TPU and the portable XLA
-lowering elsewhere; ``pallas`` forces the compiled Pallas path (TPU);
-``interpret`` forces the Pallas kernels through the interpreter so the
-full kernel code path runs on CPU containers (tests / parity checks).
+Modes: ``auto`` lets ``kernels/routing.py`` pick, per call, the Pallas
+kernel where it compiles for the platform and operand dtype and the XLA
+lowering elsewhere; ``pallas`` forces the compiled Pallas path (TPU) and
+raises where it cannot compile; ``interpret`` forces the Pallas kernels
+through the interpreter so the full kernel code path runs on CPU
+containers (tests / parity checks).  ``route_stats()`` counts kernel
+calls per route and the calls that took an exact host fallback.
 
-All device work runs under ``jax.experimental.enable_x64`` — fact values
+All device work runs under ``jax.enable_x64(True)`` — fact values
 and packed (id, attr) keys are genuine 64-bit — and behind a lock, because
-the engine's PF/PW thread pools may issue primitives concurrently.
+the engine's PF/PW thread pools may issue primitives concurrently.  A
+backend built with a ``device`` (one per shard worker) also runs it in
+that device's default-device scope, so its uploads, resident columns
+and mirrors live there.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import functools
-import os
 import threading
 
 import numpy as np
@@ -72,6 +79,7 @@ from repro.backend.device_cache import (DeviceArrayCache, MirrorRuns,
                                         SortWorkCounter, TransferCounter)
 from repro.backend.handles import DeviceCol, merge_bounds
 from repro.backend.numpy_ops import NumpyOps
+from repro.kernels import routing
 
 INT64_MAX = np.iinfo(np.int64).max
 INT64_MIN = np.iinfo(np.int64).min
@@ -95,14 +103,16 @@ def _jitted():
     def neighbor_mask(x):
         return jnp.concatenate([jnp.ones((1,), bool), x[1:] != x[:-1]])
 
-    @functools.partial(
-        jax.jit, static_argnames=("block", "force_pallas", "interpret"))
-    def semi_join(keys, bound, block, force_pallas, interpret):
-        s = device_sort(bound, block=block, force_pallas=force_pallas,
-                        interpret=interpret)
+    @jax.jit
+    def member_sorted(keys, s):
         pos = jnp.clip(jnp.searchsorted(s, keys, side="left"),
                        0, s.shape[0] - 1)
         return s[pos] == keys
+
+    def semi_join(keys, bound, block, force_pallas, interpret):
+        s = device_sort(bound, block=block, force_pallas=force_pallas,
+                        interpret=interpret)
+        return member_sorted(keys, s)
 
     @functools.partial(jax.jit, static_argnames=())
     def stable_sort_perm_xla(keys, n_real):
@@ -136,20 +146,29 @@ def _jitted():
     def gather(vals, perm):
         return vals[perm]
 
-    @functools.partial(
-        jax.jit, static_argnames=("block", "force_pallas", "interpret"))
+    @functools.partial(jax.jit, static_argnames=("n",))
+    def head(x, n):
+        return x[:n]
+
+    @jax.jit
+    def repad_max(bound, n_bound):
+        lane_b = jnp.arange(bound.shape[0], dtype=jnp.int64)
+        return jnp.where(lane_b < n_bound, bound, jnp.iinfo(jnp.int64).max)
+
+    @jax.jit
+    def member_sorted_n(keys, s, n_bound):
+        pos = jnp.clip(jnp.searchsorted(s, keys, side="left"),
+                       0, s.shape[0] - 1)
+        return (s[pos] == keys) & (pos < n_bound)
+
     def semi_join_n(keys, bound, n_bound, block, force_pallas, interpret):
         """Handle-tier semi join: pads are garbage, so the bound side is
         re-padded here and membership is bounded by ``n_bound`` —
-        sentinel-value collisions are structurally impossible."""
-        cap_b = bound.shape[0]
-        lane_b = jnp.arange(cap_b, dtype=jnp.int64)
-        b = jnp.where(lane_b < n_bound, bound, jnp.iinfo(jnp.int64).max)
-        s = device_sort(b, block=block, force_pallas=force_pallas,
-                        interpret=interpret)
-        pos = jnp.clip(jnp.searchsorted(s, keys, side="left"),
-                       0, cap_b - 1)
-        return (s[pos] == keys) & (pos < n_bound)
+        sentinel-value collisions are structurally impossible.  The sort
+        is its own program, shared with every other caller."""
+        s = device_sort(repad_max(bound, n_bound), block=block,
+                        force_pallas=force_pallas, interpret=interpret)
+        return member_sorted_n(keys, s, n_bound)
 
     @functools.partial(jax.jit, static_argnames=())
     def gather_clip(vals, idx):
@@ -365,7 +384,9 @@ def _jitted():
     return {"neighbor_mask": neighbor_mask, "semi_join": semi_join,
             "stable_sort_perm_xla": stable_sort_perm_xla,
             "dedup_rows_xla": dedup_rows_xla, "gather": gather,
+            "head": head,
             "extend_buffer": extend_buffer, "semi_join_n": semi_join_n,
+            "repad_max": repad_max, "member_sorted_n": member_sorted_n,
             "gather_clip": gather_clip, "pack_pairs": pack_pairs,
             "sort_pairs_xla": sort_pairs_xla, "fresh_pairs": fresh_pairs,
             "batch_probe_j": batch_probe_j, "test_mask": test_mask,
@@ -392,10 +413,11 @@ class JaxOps(Ops):
     def __init__(self, mode: str = "auto", block: int = 1024,
                  min_bucket: int | None = None,
                  cache_bytes: int = 256 << 20,
-                 compress: bool | None = None) -> None:
+                 compress: bool | None = None, device=None) -> None:
         if mode not in ("auto", "pallas", "interpret"):
             raise ValueError(f"unknown JaxOps mode: {mode!r}")
         self.mode = mode
+        self.device = device
         self.interpret = mode == "interpret"
         self.force_pallas = mode in ("pallas", "interpret")
         self.block = block
@@ -406,12 +428,15 @@ class JaxOps(Ops):
         self.transfers = TransferCounter()
         self.sort_work = SortWorkCounter()
         self.cache = DeviceArrayCache(cache_bytes)
+        # kernel calls per route ("pallas" / "xla") and exact host
+        # fallbacks per primitive (route_stats() reads them)
+        self.routes: collections.Counter = collections.Counter()
+        self.host_fallbacks: collections.Counter = collections.Counter()
         # compressed device-resident columns: on by default (decoded
         # results are bit-identical by construction); REPRO_COMPRESS=0
         # or compress=False restores raw int64 buffers end to end
         if compress is None:
-            env = os.environ.get("REPRO_COMPRESS")
-            compress = env is None or env not in ("0", "false", "off")
+            compress = codecs.compress_default()
         self.compress = bool(compress)
         # codec accounting (monotone; residency_stats() reads them)
         self._res_counts = {"for": 0, "dict": 0, "rle": 0,
@@ -432,12 +457,27 @@ class JaxOps(Ops):
         return max(32, 1 << (max(n, 1) - 1).bit_length())
 
     def _x64(self):
-        from jax.experimental import enable_x64
-        return enable_x64()
-
-    def _use_pallas(self) -> bool:
+        """Scope for device work: x64, this backend's route tally (every
+        kernel call's routing decision counts in ``self.routes``), and
+        its default device, if it has one."""
         import jax
-        return self.force_pallas or jax.default_backend() == "tpu"
+        stack = contextlib.ExitStack()
+        stack.enter_context(jax.enable_x64(True))
+        stack.enter_context(routing.tally(self.routes))
+        if self.device is not None:
+            stack.enter_context(jax.default_device(self.device))
+        return stack
+
+    def _host_fallback(self, name: str):
+        """Count one exact host-path call of primitive ``name``."""
+        self.host_fallbacks[name] += 1
+        return self._host
+
+    def route_stats(self) -> dict:
+        """Kernel calls per route and host fallbacks per primitive."""
+        return {"pallas": self.routes["pallas"],
+                "xla": self.routes["xla"],
+                "host": dict(self.host_fallbacks)}
 
     @staticmethod
     def _pad(a: np.ndarray, cap: int, fill: int) -> np.ndarray:
@@ -469,14 +509,26 @@ class JaxOps(Ops):
     def _to_dev(self, a: np.ndarray):
         """Upload (counted).  Must run inside the x64 scope or int64
         truncates to int32."""
+        import jax
         import jax.numpy as jnp
         self.transfers.count_h2d(a.nbytes)
-        return jnp.asarray(a)
+        if self.device is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, self.device)
 
     def _to_host(self, a) -> np.ndarray:
         out = np.asarray(a)
         self.transfers.count_d2h(out.nbytes)
         return out
+
+    def _prefix_to_host(self, a, n: int) -> np.ndarray:
+        """Download the first ``n`` lanes of ``a``: a device slice to
+        ``n``'s bucket (one program per bucket, not one per length),
+        then the exact slice on the host."""
+        b = self._bucket(n)
+        if b < a.shape[0]:
+            a = _jitted()["head"](a, n=b)
+        return self._to_host(a)[:n]
 
     def _sort_args(self) -> dict:
         return {"block": self.block, "force_pallas": self.force_pallas,
@@ -908,7 +960,8 @@ class JaxOps(Ops):
         # left pads (MAX) must not match real right keys and right pads
         # (MIN) must not match real left keys
         if lkeys.min() == INT64_MIN or rkeys.max() == INT64_MAX:
-            return self._host.join_pairs(lkeys, rkeys)
+            return self._host_fallback("join_pairs").join_pairs(lkeys,
+                                                                rkeys)
         import jax  # noqa: F401  (ensures backend init before lock)
         from repro.kernels.mergejoin.ops import merge_join_bounded
         cap = self._bucket(max(n, m))
@@ -945,7 +998,8 @@ class JaxOps(Ops):
             # valid pairs are a prefix: pack (li << 32 | ri) on device and
             # download the prefix once — one transfer, not three
             from repro.kernels.mergejoin.ops import pack_pairs_bounded
-            packed = self._to_host(pack_pairs_bounded(li, ri, valid)[:total])
+            packed = self._prefix_to_host(
+                pack_pairs_bounded(li, ri, valid), total)
         return packed >> 32, packed & 0xFFFFFFFF
 
     def _narrow_h2d(self, a: np.ndarray, cap: int, fill: int,
@@ -972,7 +1026,9 @@ class JaxOps(Ops):
         with self._lock, self._x64():
             xp = self._narrow_h2d(x, self._bucket(n), INT64_MAX,
                                   int(x[0]), int(x[-1]))
-            if self._use_pallas():
+            if routing.route("unique_mask", xp,
+                             force_pallas=self.force_pallas,
+                             interpret=self.interpret):
                 from repro.kernels.uniquefilter.uniquefilter import \
                     unique_mask_sorted
                 mask = unique_mask_sorted(xp, block=self.block,
@@ -990,7 +1046,7 @@ class JaxOps(Ops):
         if n == 0 or m == 0:
             return np.zeros(n, bool)
         if keys.max() == INT64_MAX:  # would match the bound-side pads
-            return self._host.semi_join(keys, bound)
+            return self._host_fallback("semi_join").semi_join(keys, bound)
         with self._lock, self._x64():
             kp = self._narrow_h2d(keys, self._bucket(n), INT64_MAX,
                                   int(keys.min()), int(keys.max()))
@@ -1016,7 +1072,7 @@ class JaxOps(Ops):
             # the XLA fallback is pad-flag based and sentinel-safe, but a
             # width overflow AND a sentinel collision means genuinely
             # adversarial keys: take the exact host path
-            return self._host.dedup_rows(cols)
+            return self._host_fallback("dedup_rows").dedup_rows(cols)
         import jax.numpy as jnp
         with self._lock, self._x64():
             padded = tuple(self._to_dev(self._pad(c, cap, INT64_MAX))
@@ -1181,8 +1237,7 @@ class JaxOps(Ops):
         jt = _jitted()
 
         def thunk():
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with self._x64():
                 self._res_counts["decode_calls"] += 1
                 if codec.kind == "for":
                     return jt["decode_for"](codes, codec.ref)
@@ -1329,7 +1384,7 @@ class JaxOps(Ops):
         if isinstance(h.data, np.ndarray):
             return h.data[: h.n]
         with self._lock, self._x64():
-            return self._to_host(h.data[: h.n])
+            return self._prefix_to_host(h.data, h.n)
 
     def iota_h(self, n: int) -> DeviceCol:
         if n == 0:
@@ -1595,7 +1650,8 @@ class JaxOps(Ops):
     def _join_gather_host(self, lkeys, rkeys, lpay, rpay, verify, algo):
         """Exact host path for sentinel-adversarial keys (downloads and
         re-uploads — counted; correctness guard, not a fast path)."""
-        li, ri = self._host.join(lkeys.host(), rkeys.host(), algo)
+        li, ri = self._host_fallback("join_gather").join(
+            lkeys.host(), rkeys.host(), algo)
         for vl, vr in verify:
             if len(li) == 0:
                 break
@@ -1750,6 +1806,28 @@ class JaxOps(Ops):
             out["resident_bytes_coded"] += coded
         return out
 
+    def resident_devices(self) -> set:
+        """The devices that hold this backend's cached device buffers
+        (resident columns, mirrors, memoized handles)."""
+        import jax
+        with self.cache._lock:
+            todo = [e.value for e in self.cache._entries.values()]
+        out: set = set()
+        while todo:
+            v = todo.pop()
+            if isinstance(v, jax.Array):
+                out |= v.devices()
+            elif isinstance(v, dict):
+                todo.extend(v.values())
+            elif isinstance(v, (list, tuple)):
+                todo.extend(v)
+            elif isinstance(v, DeviceCol):
+                todo.extend((v._data, v.codes))
+            elif dataclasses.is_dataclass(v):
+                todo.extend(getattr(v, f.name)
+                            for f in dataclasses.fields(v))
+        return out
+
     def batch_probe(self, sorted_keys, probes, *, cache_key=None,
                     version: int | None = None):
         probes = np.asarray(probes, np.int64)
@@ -1786,7 +1864,9 @@ class JaxOps(Ops):
                                         INT64_MAX))
             res = self._to_host(_jitted()["batch_probe_j"](
                 buf, n_real, pd, block=self.block,
-                use_pallas=self._use_pallas(),
+                use_pallas=routing.route("probe", pd,
+                                         force_pallas=self.force_pallas,
+                                         interpret=self.interpret),
                 interpret=self.interpret))
         return res[0, :n].copy(), res[1, :n].copy()
 

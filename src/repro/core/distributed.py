@@ -28,8 +28,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.kernels.prefix import prefix_sum
 
 SENTINEL = jnp.iinfo(jnp.int64).max
 
@@ -74,18 +75,18 @@ def bucket_scatter(dest: jnp.ndarray, payload: jnp.ndarray, n_dev: int,
     ``sentinel`` fills empty slots; compressed-wire lanes (sub-int64
     payload dtypes) pass their own dtype's max, since the int64 default
     does not fit."""
-    n = dest.shape[0]
     d = jnp.where(valid, dest, n_dev)
-    order = jnp.argsort(d)
-    d_sorted = d[order]
-    payload_sorted = payload[order]
-    starts = jnp.searchsorted(d_sorted, jnp.arange(n_dev, dtype=d.dtype))
-    idx_in_bucket = jnp.arange(n) - starts[jnp.clip(d_sorted, 0, n_dev - 1)]
-    ok = (d_sorted < n_dev) & (idx_in_bucket < slot_cap)
-    pos = jnp.where(ok, d_sorted * slot_cap + idx_in_bucket, n_dev * slot_cap)
+    # rank of each row among the rows bound for its device, in input
+    # order (one prefix sum per destination; no sort)
+    idx_in_bucket = jnp.zeros(d.shape, jnp.int64)
+    for k in range(n_dev):
+        hit = d == k
+        idx_in_bucket = jnp.where(hit, prefix_sum(hit) - 1, idx_in_bucket)
+    ok = (d < n_dev) & (idx_in_bucket < slot_cap)
+    pos = jnp.where(ok, d * slot_cap + idx_in_bucket, n_dev * slot_cap)
     buf = jnp.full((n_dev * slot_cap,), sentinel, dtype=payload.dtype)
-    buf = buf.at[pos].set(payload_sorted, mode="drop")
-    overflow = jnp.sum((d_sorted < n_dev) & (idx_in_bucket >= slot_cap))
+    buf = buf.at[pos].set(payload, mode="drop")
+    overflow = jnp.sum((d < n_dev) & (idx_in_bucket >= slot_cap))
     return buf, overflow
 
 
@@ -105,7 +106,7 @@ def join_expand_bounded(
     lo = jnp.searchsorted(r_sorted_key, l_key, side="left")
     hi = jnp.searchsorted(r_sorted_key, l_key, side="right")
     counts = jnp.where(l_valid, hi - lo, 0)
-    starts = jnp.cumsum(counts) - counts
+    starts = prefix_sum(counts) - counts
     total = jnp.sum(counts)
     out_idx = jnp.arange(out_cap)
     row = jnp.clip(jnp.searchsorted(starts, out_idx, side="right") - 1,
@@ -136,7 +137,7 @@ def compact_masked(values_sorted: jnp.ndarray, mask: jnp.ndarray, cap: int,
                    fill) -> jnp.ndarray:
     """Keep masked entries of a sorted array, left-packed to ``cap`` —
     a cumsum scatter instead of a sort (§Perf: closure iteration 2)."""
-    pos = jnp.where(mask, jnp.cumsum(mask) - 1, cap)
+    pos = jnp.where(mask, prefix_sum(mask) - 1, cap)
     out = jnp.full((cap,), fill, values_sorted.dtype)
     return out.at[pos].set(values_sorted, mode="drop")
 
@@ -265,13 +266,13 @@ class DistributedClosure:
         spec = P(self.axis_names)
         step = functools.partial(closure_step, cfg=self.cfg,
                                  axis_names=self.axis_names, n_dev=self.n_dev)
-        self._step = jax.jit(shard_map(
+        self._step = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=({k: spec for k in
                        ("edges", "closure", "delta", "fresh", "overflow")},),
             out_specs={k: spec for k in
                        ("edges", "closure", "delta", "fresh", "overflow")},
-            check_rep=False))
+            check_vma=False))
 
     # -- state construction --------------------------------------------------
     def init_state(self, src: np.ndarray, dst: np.ndarray) -> dict:

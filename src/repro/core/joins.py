@@ -4,9 +4,9 @@
   tightly packed per-variable columns — compressible, vector friendly) and
   row-major ``RR`` (one ``[rows, vars]`` matrix) — the paper benchmarks both.
 * Join algorithms: ``MJ`` (parallel sort-merge join — fork-join instance 2)
-  and ``HJ`` (hash join).  TPU adaptation (see DESIGN.md): HJ keeps the hash
-  as a *bucketizer* and probes with binary search on the hashed keys —
-  pointer-chasing open addressing does not vectorize on TPU.
+  and ``HJ`` (hash join).  TPU adaptation (see docs/ARCHITECTURE.md): HJ
+  keeps the hash as a *bucketizer* and probes with binary search on the
+  hashed keys — pointer-chasing open addressing does not vectorize on TPU.
 * ``SU`` unique filter: the paper's parallel sort-merge unique filter —
   lexsort + neighbor compare.
 

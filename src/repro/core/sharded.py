@@ -184,7 +184,7 @@ class _ShardWorker(HiperfactEngine):
     """
 
     def __init__(self, config: EngineConfig, shard: int, n_shards: int,
-                 parent: "ShardedEngine") -> None:
+                 parent: "ShardedEngine", device=None) -> None:
         super().__init__(config)
         self.shard = shard
         self.n_shards = n_shards
@@ -194,9 +194,10 @@ class _ShardWorker(HiperfactEngine):
         # be redundant at best, a local full infer() at worst
         self._demand_skip = True
         # per-shard counters + device-array cache: a fresh Ops instance
-        # (get_backend shares one per process; jit caches stay shared)
+        # (get_backend shares one per process; jit caches stay shared),
+        # placed on this shard's device of the exchange mesh
         self.ops = fresh_backend(config.backend,
-                                 compress=config.compress)
+                                 compress=config.compress, device=device)
         self.store = FactStore(config.index_backend, ops=self.ops)
         self.store.strings = parent.store.strings  # ONE dictionary
         self._result_cache = None  # the parent caches query results
@@ -261,8 +262,16 @@ class ShardedEngine(HiperfactEngine):
         super().__init__(dataclasses.replace(config, shards=1))
         self.config = config
         self.n_shards = _resolve_shards(config)
+        from repro.distributed.pipeline import FrontierExchange
+        # a device backend gets one device per shard (or raises here)
+        self.exchange = FrontierExchange(
+            self.n_shards, prefer_device=config.backend != "numpy",
+            compress=config.compress)
+        devices = (list(self.exchange.mesh.devices.flat)
+                   if self.exchange.device else [None] * self.n_shards)
         wcfg = dataclasses.replace(config, shards=1)
-        self.workers = [_ShardWorker(wcfg, s, self.n_shards, self)
+        self.workers = [_ShardWorker(wcfg, s, self.n_shards, self,
+                                     device=devices[s])
                         for s in range(self.n_shards)]
         # ftype -> registered view components (None = replicated)
         self._views: dict[str, set] = {}
@@ -270,10 +279,6 @@ class ShardedEngine(HiperfactEngine):
         self._table_names: list[str] = []
         self._outbox: list[list] = [[] for _ in range(self.n_shards)]
         self._lock = threading.Lock()
-        from repro.distributed.pipeline import FrontierExchange
-        self.exchange = FrontierExchange(
-            self.n_shards, prefer_device=config.backend != "numpy",
-            compress=config.compress)
         self.exchange_log: list[dict] = []
         # per-types-tuple memo of gathered snapshots, invalidated by the
         # shard version-token vector (satellite: repeat non-decomposable

@@ -20,10 +20,10 @@ one ``lax.all_to_all`` under ``shard_map`` over a 1-D ``shards`` mesh
 (``distributed.sharding.fact_mesh``).  Send-buffer capacity is exact:
 the host knows every bucket count, so ``slot_cap`` is the
 power-of-two-rounded max bucket — no overflow/retry loop, and the jit
-cache only sees log-many ``(in_cap, slot_cap)`` shapes.  When the
-process has fewer devices than shards (or the engine runs the numpy
-backend) the same exchange runs as a host permute with identical
-semantics and byte accounting.
+cache only sees log-many ``(in_cap, slot_cap)`` shapes.  The numpy
+backend runs the same exchange as a host permute with identical
+semantics and byte accounting; a device backend with fewer devices than
+shards is an error.
 """
 
 from __future__ import annotations
@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(block_fn, stacked_params, h: jnp.ndarray, *, mesh: Mesh,
@@ -94,11 +93,11 @@ def pipeline_apply(block_fn, stacked_params, h: jnp.ndarray, *, mesh: Mesh,
             jnp.where(sid == n_stages - 1, out, jnp.zeros_like(out)), axis)
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P(axis), P()),   # params stage-sharded; h replicated
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stacked_params, h)
 
 
@@ -124,8 +123,9 @@ class FrontierExchange:
     Device path: one jitted ``shard_map`` over ``fact_mesh(n_shards)``
     running ``bucket_scatter`` per lane + ``lax.all_to_all`` — the
     exact transport of ``core.distributed.closure_step``, generalized
-    to arbitrary fact rows.  Host path (too few devices, or numpy
-    backend): the same permutation on host arrays.
+    to arbitrary fact rows.  Host path (numpy backend only): the same
+    permutation on host arrays.  A device backend with fewer devices
+    than shards raises instead of falling back to the host.
     """
 
     def __init__(self, n_shards: int, prefer_device: bool = True,
@@ -134,16 +134,13 @@ class FrontierExchange:
         self.mesh = None
         self._fns: dict[tuple, object] = {}
         if compress is None:
-            import os
-            env = os.environ.get("REPRO_COMPRESS")
-            compress = env is None or env not in ("0", "false", "off")
+            from repro.backend.codecs import compress_default
+            compress = compress_default()
         self.compress = bool(compress)
         if prefer_device and n_shards > 1:
-            try:
-                from repro.distributed.sharding import fact_mesh
-                self.mesh = fact_mesh(n_shards)
-            except Exception:
-                self.mesh = None  # host fallback
+            # raises when the process has fewer devices than shards
+            from repro.distributed.sharding import fact_mesh
+            self.mesh = fact_mesh(n_shards)
 
     @property
     def device(self) -> bool:
@@ -171,10 +168,10 @@ class FrontierExchange:
                 out.append(_exchange(buf, (axis,), D, slot_cap)[None, :])
             return tuple(out)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=self.mesh,
             in_specs=(P(axis),) * 4, out_specs=(P(axis),) * 3,
-            check_rep=False))
+            check_vma=False))
         self._fns[(in_cap, slot_cap, sentinels)] = fn
         return fn
 

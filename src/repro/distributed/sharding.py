@@ -179,7 +179,8 @@ def fact_mesh(n_shards: int, axis: str = FACT_AXIS) -> Mesh:
     when the process has too few devices instead of silently folding
     into a degenerate mesh (CPU containers must set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before*
-    jax initializes).
+    jax initializes).  Shard ``i`` sits on ``jax.devices()[i]``, the
+    device its worker's backend places its buffers on.
     """
     have = jax.device_count()
     if have < n_shards:
@@ -187,7 +188,7 @@ def fact_mesh(n_shards: int, axis: str = FACT_AXIS) -> Mesh:
             f"fact_mesh({n_shards}) needs {n_shards} devices but jax sees "
             f"{have}; set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n_shards} before the first jax call")
-    return jax.make_mesh((n_shards,), (axis,))
+    return Mesh(np.array(jax.devices()[:n_shards]), (axis,))
 
 
 def fact_frontier_spec(axis: str = FACT_AXIS) -> P:

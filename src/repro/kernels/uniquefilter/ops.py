@@ -5,6 +5,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.routing import use_pallas
 from repro.kernels.sortmerge.ops import device_sort
 from repro.kernels.uniquefilter.uniquefilter import unique_mask_sorted
 
@@ -20,7 +21,8 @@ def unique_sorted_bounded(x: jnp.ndarray, force_pallas: bool = False,
     if jnp.issubdtype(x.dtype, jnp.integer) and x.dtype != jnp.int64:
         x = x.astype(jnp.int64)
     s = device_sort(x, force_pallas=force_pallas, interpret=interpret)
-    if force_pallas or jax.default_backend() == "tpu":
+    if use_pallas("unique_mask", s.dtype, force_pallas=force_pallas,
+                  interpret=interpret):
         mask = unique_mask_sorted(s, interpret=interpret)
     else:
         mask = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])

@@ -24,6 +24,8 @@ def main() -> None:
     ap.add_argument("--write-ops", type=int, default=10)
     ap.add_argument("--reads", type=int, default=25)
     args = ap.parse_args()
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     from repro.core import EngineConfig, Fact, HiperfactEngine, Rule
     from repro.core.conditions import AddAction, cond, term

@@ -25,3 +25,18 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
 @pytest.fixture
 def subproc():
     return run_with_devices
+
+
+def run_on_devices(func, *args, n_devices: int, timeout: int = 600):
+    """Call a module-level test helper ``func(*args)`` in a subprocess
+    that sees ``n_devices`` host devices (device backends place one
+    shard per device, and this process has one)."""
+    code = (f"import sys; sys.path.insert(0, {os.path.dirname(__file__)!r})\n"
+            f"from {func.__module__} import {func.__name__} as f\n"
+            f"f(*{args!r})\n")
+    return run_with_devices(code, n_devices=n_devices, timeout=timeout)
+
+
+@pytest.fixture
+def on_devices():
+    return run_on_devices

@@ -70,7 +70,15 @@ def _reference_rows():
 @pytest.mark.parametrize("backend", ["numpy", "jax-interpret"])
 @pytest.mark.parametrize("shards", [1, 2])
 @pytest.mark.parametrize("mode", ["full", "delta", "demand"])
-def test_point_query_parity(mode, shards, backend):
+def test_point_query_parity(mode, shards, backend, on_devices):
+    if backend != "numpy" and shards > 1:
+        on_devices(check_point_query_parity, mode, shards, backend,
+                   n_devices=shards)
+    else:
+        check_point_query_parity(mode, shards, backend)
+
+
+def check_point_query_parity(mode, shards, backend):
     ref = _reference_rows()
     e = _build(_cfg(backend, eval_mode=mode, shards=shards))
     if mode != "demand":

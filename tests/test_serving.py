@@ -345,7 +345,15 @@ def test_property_random_interleavings_match_oracle(seed):
 @pytest.mark.parametrize("backend", ["numpy", "jax-interpret"])
 @pytest.mark.parametrize("shards", [1, 2])
 @pytest.mark.parametrize("mode", ["full", "delta", "demand"])
-def test_served_requery_parity_matrix(mode, shards, backend):
+def test_served_requery_parity_matrix(mode, shards, backend, on_devices):
+    if backend != "numpy" and shards > 1:
+        on_devices(check_served_requery_parity, mode, shards, backend,
+                   n_devices=shards)
+    else:
+        check_served_requery_parity(mode, shards, backend)
+
+
+def check_served_requery_parity(mode, shards, backend):
     extra = Fact("edge", f"c0_n{CHAIN_LEN}", "to", f"c0_n{CHAIN_LEN + 1}")
     steps = [("append", [extra]), ("delete", [extra])]
 
