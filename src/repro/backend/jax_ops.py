@@ -80,6 +80,7 @@ from repro.backend.device_cache import (DeviceArrayCache, MirrorRuns,
 from repro.backend.handles import DeviceCol, merge_bounds
 from repro.backend.numpy_ops import NumpyOps
 from repro.kernels import routing
+from repro.tracing import span
 
 INT64_MAX = np.iinfo(np.int64).max
 INT64_MIN = np.iinfo(np.int64).min
@@ -512,12 +513,15 @@ class JaxOps(Ops):
         import jax
         import jax.numpy as jnp
         self.transfers.count_h2d(a.nbytes)
-        if self.device is None:
-            return jnp.asarray(a)
-        return jax.device_put(a, self.device)
+        with span("hf.h2d", bytes=a.nbytes):
+            if self.device is None:
+                return jnp.asarray(a)
+            return jax.device_put(a, self.device)
 
     def _to_host(self, a) -> np.ndarray:
-        out = np.asarray(a)
+        with span("hf.d2h") as sp:
+            out = np.asarray(a)
+            sp.set_metadata(bytes=out.nbytes)
         self.transfers.count_d2h(out.nbytes)
         return out
 
@@ -744,10 +748,11 @@ class JaxOps(Ops):
             d = n - runs.src_n
             dcap = self._delta_bucket(d)
             if dcap <= cap:  # the slice window slides back if needed
-                sk, perm, merged = merge_sorted_mirror_impl(
-                    buf, runs.tagged, runs.n, runs.src_n, n, kmin,
-                    runs.kmin, dcap=dcap, tag_bits=tb,
-                    **self._sort_args())
+                with span("hf.index", mode="merge", rows=d):
+                    sk, perm, merged = merge_sorted_mirror_impl(
+                        buf, runs.tagged, runs.n, runs.src_n, n, kmin,
+                        runs.kmin, dcap=dcap, tag_bits=tb,
+                        **self._sort_args())
                 self.cache.put(key, version, MirrorRuns(
                     tagged=merged, n=runs.n + d, kmin=kmin, cap=cap,
                     tag_bits=tb, merges=runs.merges + 1,
@@ -775,8 +780,9 @@ class JaxOps(Ops):
                 # resident buffer's domain (same cid as the colbuf)
                 ckeys = codecs.encode_with(codec, ckeys).astype(np.int64)
             cbuf = self._to_dev(self._pad(ckeys, ccap, INT64_MAX))
-            sk, permc = self._stable_perm_device(
-                cbuf, m, int(ckeys.min()), int(ckeys.max()))
+            with span("hf.index", mode="rebuild", rows=m):
+                sk, permc = self._stable_perm_device(
+                    cbuf, m, int(ckeys.min()), int(ckeys.max()))
             rows_dev = self._to_dev(self._pad(rows.astype(np.int64),
                                               ccap, 0))
             perm = _jitted()["gather"](rows_dev, permc)
@@ -801,7 +807,8 @@ class JaxOps(Ops):
             else:
                 self.cache.invalidate(key)
             return sk, perm, m
-        sk, perm = self._stable_perm_device(buf, n, kmin, kmax)
+        with span("hf.index", mode="rebuild", rows=n):
+            sk, perm = self._stable_perm_device(buf, n, kmin, kmax)
         self.sort_work.count_full(cap * 8, compaction=compacting,
                                   rebuild=rebuild)
         if fits:

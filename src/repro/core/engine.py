@@ -31,6 +31,7 @@ from repro.core.islands import (_dead_window_rows, _frontier_rows,
                                 build_islands, evaluate_rule)
 from repro.core.joins import Bindings
 from repro.core.store import FactStore, TypedFactTable, base_fact_type
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -265,6 +266,11 @@ def _resolve_shards(config: EngineConfig) -> int:
     return n
 
 
+def _plan_passes(plan: tuple | None) -> int:
+    """Passes an evaluation plan of ``_begin_rule_eval`` asks for."""
+    return 1 if plan is None or plan[0] == "init" else len(plan[1])
+
+
 class HiperfactEngine:
     def __new__(cls, config: EngineConfig | None = None, *args, **kwargs):
         # shards > 1 transparently constructs the hash-partitioned
@@ -305,7 +311,7 @@ class HiperfactEngine:
         self._n_compensated = 0
         self._comp_reported = 0
         self._pk_memo = _PackedKeyMemo()
-        self.load_seconds = 0.0
+        self._n_infer = 0  # infer() calls: the id its spans share
         self.last_infer: InferStats = InferStats()
         from repro.core.querycache import QueryResultCache, RankNCache
         self.query_cache = (RankNCache() if self.config.query_cache
@@ -376,22 +382,20 @@ class HiperfactEngine:
             self.add_rule(r)
 
     def insert_facts(self, facts: list[Fact]) -> int:
-        t0 = time.perf_counter()
         n = 0
-        for ftype, cols in facts_to_columns(facts, self.store.strings).items():
-            n += self._insert_columns(
-                ftype, cols["id"], cols["attr"], cols["val"], cols["valtype"])
-        self.load_seconds += time.perf_counter() - t0
+        with span("hf.load", facts=len(facts)):
+            for ftype, cols in facts_to_columns(facts,
+                                                self.store.strings).items():
+                n += self._insert_columns(ftype, cols["id"], cols["attr"],
+                                          cols["val"], cols["valtype"])
         return n
 
     def insert_columns(self, ftype: str, ids, attrs, vals, valtypes) -> int:
-        t0 = time.perf_counter()
-        n = self._insert_columns(ftype, np.asarray(ids, np.int32),
-                                 np.asarray(attrs, np.int32),
-                                 np.asarray(vals, np.int64),
-                                 np.asarray(valtypes, np.int8))
-        self.load_seconds += time.perf_counter() - t0
-        return n
+        with span("hf.load", facts=len(ids)):
+            return self._insert_columns(ftype, np.asarray(ids, np.int32),
+                                        np.asarray(attrs, np.int32),
+                                        np.asarray(vals, np.int64),
+                                        np.asarray(valtypes, np.int8))
 
     def trees(self) -> DerivationTrees:
         if self._trees is None:
@@ -401,46 +405,49 @@ class HiperfactEngine:
     # ---------------------------------------------------------------- write
     def _insert_columns(self, ftype: str, ids, attrs, vals, valtypes,
                         asserted: bool = True) -> int:
-        table = self.store.table(ftype)
-        if self.config.unique == "SU":
-            if ((is_handle(ids) or is_handle(attrs) or is_handle(vals))
-                    and table.n_dead == 0 and not asserted):
-                # device pipeline: dedup + anti-join on handles; only
-                # genuinely fresh rows are ever downloaded.  Tombstoned
-                # tables take the host path (the alive filter is host
-                # state the resident columns don't carry); asserted
-                # inserts do too (existing matches must be re-marked).
-                n = self._insert_handles(table, ids, attrs, vals, valtypes)
-            else:
+        rows_in = ids.n if is_handle(ids) else len(ids)
+        with span("hf.write", type=ftype, rows_in=rows_in) as sp:
+            table = self.store.table(ftype)
+            if self.config.unique == "SU":
+                if ((is_handle(ids) or is_handle(attrs) or is_handle(vals))
+                        and table.n_dead == 0 and not asserted):
+                    # device pipeline: dedup + anti-join on handles; only
+                    # genuinely fresh rows are ever downloaded.  Tombstoned
+                    # tables take the host path (the alive filter is host
+                    # state the resident columns don't carry); asserted
+                    # inserts do too (existing matches must be re-marked).
+                    n = self._insert_handles(table, ids, attrs, vals, valtypes)
+                else:
+                    ids, attrs, vals = (x.host() if is_handle(x) else x
+                                        for x in (ids, attrs, vals))
+                    # parallel-sort-merge unique: batch-dedup then anti-join
+                    # vs table
+                    if len(ids) > 1:
+                        keep = self.ops.dedup_rows([ids, attrs, vals])
+                        ids, attrs, vals, valtypes = (
+                            ids[keep], attrs[keep], vals[keep], valtypes[keep])
+                    rowof = _match_rows(table, ids, attrs, vals, self.ops,
+                                        self._pk_memo)
+                    exists = rowof >= 0
+                    if exists.any():
+                        if asserted:
+                            # re-asserting a currently-derived fact: pin it
+                            # so support collapse alone cannot kill it
+                            table.mark_asserted(rowof[exists])
+                        fresh = ~exists
+                        ids, attrs, vals, valtypes = (
+                            ids[fresh], attrs[fresh], vals[fresh],
+                            valtypes[fresh])
+                    n = table.insert(ids, attrs, vals, valtypes, dedup=False,
+                                     asserted=asserted)
+            else:  # HU: incremental hashtable dedup inside the table
                 ids, attrs, vals = (x.host() if is_handle(x) else x
                                     for x in (ids, attrs, vals))
-                # parallel-sort-merge unique: batch-dedup then anti-join
-                # vs table
-                if len(ids) > 1:
-                    keep = self.ops.dedup_rows([ids, attrs, vals])
-                    ids, attrs, vals, valtypes = (
-                        ids[keep], attrs[keep], vals[keep], valtypes[keep])
-                rowof = _match_rows(table, ids, attrs, vals, self.ops,
-                                    self._pk_memo)
-                exists = rowof >= 0
-                if exists.any():
-                    if asserted:
-                        # re-asserting a currently-derived fact: pin it
-                        # so support collapse alone cannot kill it
-                        table.mark_asserted(rowof[exists])
-                    fresh = ~exists
-                    ids, attrs, vals, valtypes = (
-                        ids[fresh], attrs[fresh], vals[fresh],
-                        valtypes[fresh])
-                n = table.insert(ids, attrs, vals, valtypes, dedup=False,
+                n = table.insert(ids, attrs, vals, valtypes, dedup=True,
                                  asserted=asserted)
-        else:  # HU: incremental hashtable dedup inside the table
-            ids, attrs, vals = (x.host() if is_handle(x) else x
-                                for x in (ids, attrs, vals))
-            n = table.insert(ids, attrs, vals, valtypes, dedup=True,
-                             asserted=asserted)
-        if n:
-            self._type_version[ftype] = self._type_version.get(ftype, 0) + 1
+            if n:
+                self._type_version[ftype] = self._type_version.get(ftype, 0) + 1
+            sp.set_metadata(rows_fresh=n)
         return n
 
     def _insert_handles(self, table: TypedFactTable, ids, attrs, vals,
@@ -1048,112 +1055,22 @@ class HiperfactEngine:
         trees = self.trees()
         active = trees.active_set(lazy=cfg.lazy)
         stats = InferStats()
+        self._n_infer += 1
+        infer_id = self._n_infer
         pool = (ThreadPoolExecutor(max_workers=cfg.max_workers)
                 if (cfg.tree_exec == "PF" or cfg.index_write == "PW") else None)
-        try:
-            changed = True
-            while changed and stats.iterations < cfg.max_iterations:
-                changed = False
-                stats.iterations += 1
-                # deaths since the last round (or from deletes between
-                # infer calls) that signed frontiers cannot absorb
-                # trigger the DRed scrub before the round's evaluations
-                if self._check_death_frontiers(stats):
-                    changed = True
-                round_rows = 0
-                round_emitted = 0
-                for level in trees.levels:
-                    level_rules = []
-                    for r in level:
-                        if r not in active:
-                            if not self.rules[r].is_query():
-                                stats.rules_skipped_inactive += 1
-                            continue
-                        if self.rules[r].is_query():
-                            continue  # queries run via .query()/.run_queries()
-                        if not self._rule_inputs_changed(r):
-                            stats.rules_skipped_unchanged += 1
-                            continue
-                        level_rules.append(r)
-                    if not level_rules:
-                        continue
-                    # Algorithm 2: islands + sort keys rebuilt per level
-                    # (cardinalities moved); groups own disjoint output types.
-                    groups = trees.out_groups(level_rules, set(level_rules))
-                    results: list[tuple[int, dict, dict, dict, dict]] = []
-                    if pool is not None and cfg.tree_exec == "PF" and len(groups) > 1:
-                        futs = []
-                        for g in groups:
-                            for r in g:
-                                plan = self._begin_rule_eval(r)
-                                futs.append(pool.submit(self._eval_one, r,
-                                                        plan))
-                        results = [f.result() for f in futs]
-                    else:
-                        for g in groups:
-                            for r in g:
-                                results.append(
-                                    self._eval_one(r,
-                                                   self._begin_rule_eval(r)))
-                    stats.rules_evaluated += len(results)
-                    for _, _, _, _, es in results:
-                        round_rows += es.get("rows_considered", 0)
-                        stats.delta_passes += es.get("delta_passes", 0)
-                        stats.full_evals += es.get("full_evals", 0)
-                        stats.neg_passes += es.get("neg_passes", 0)
-                        stats.replans += es.get("replans", 0)
-                    # Writes: PW = concurrent per disjoint fact type;
-                    # SW = sequential in schedule order.  Set-semantics
-                    # adds (full fallbacks), explicit deletes, then the
-                    # signed counting application.
-                    by_type_adds: dict[str, list] = {}
-                    by_type_dels: dict[str, list] = {}
-                    by_type_signed: dict[str, list] = {}
-                    for _, adds, dels, signed, _es in results:
-                        for t, cols in adds.items():
-                            by_type_adds.setdefault(t, []).append(cols)
-                        for t, cols in dels.items():
-                            by_type_dels.setdefault(t, []).append(cols)
-                        for t, batches in signed.items():
-                            by_type_signed.setdefault(t, []).extend(batches)
-
-                    def _write_type(t: str, parts: list) -> int:
-                        return self._insert_columns(
-                            t, *self._cat_parts(parts), asserted=False)
-
-                    if pool is not None and cfg.index_write == "PW" and len(by_type_adds) > 1:
-                        futs = {t: pool.submit(_write_type, t, p)
-                                for t, p in by_type_adds.items()}
-                        wrote = {t: f.result() for t, f in futs.items()}
-                    else:
-                        wrote = {t: _write_type(t, p)
-                                 for t, p in by_type_adds.items()}
-                    for t, parts in by_type_dels.items():
-                        cols = self._cat_parts(parts)
-                        ndel = self._delete_matching(t, cols[0], cols[1], cols[2])
-                        stats.facts_deleted += ndel
-                        changed |= ndel > 0
-                    for t, batches in by_type_signed.items():
-                        cnt = self._signed_counts(batches)
-                        if cnt is None:
-                            continue
-                        nn, nd = self._apply_counts(t, *cnt)
-                        stats.facts_inferred += nn
-                        stats.facts_retracted += nd
-                        round_emitted += nn
-                        changed |= (nn + nd) > 0
-                    n_new = sum(wrote.values())
-                    stats.facts_inferred += n_new
-                    round_emitted += n_new
-                    changed |= n_new > 0
-                stats.rows_considered += round_rows
-                stats.rows_emitted += round_emitted
-                stats.rounds.append({"iteration": stats.iterations,
-                                     "rows_considered": round_rows,
-                                     "rows_emitted": round_emitted})
-        finally:
-            if pool is not None:
-                pool.shutdown(wait=True)
+        with span("hf.infer", infer=infer_id):
+            try:
+                changed = True
+                while changed and stats.iterations < cfg.max_iterations:
+                    stats.iterations += 1
+                    with span("hf.round", infer=infer_id,
+                              round=stats.iterations):
+                        changed = self._round(stats, trees, active, pool,
+                                              infer_id)
+            finally:
+                if pool is not None:
+                    pool.shutdown(wait=True)
         # compensations since the last infer() — covers both in-round
         # DeleteAction absorptions and out-of-band delete_facts() calls
         stats.compensated_deletes = self._n_compensated - self._comp_reported
@@ -1162,6 +1079,122 @@ class HiperfactEngine:
         self._drain_sketch_counts(stats)
         self.last_infer = stats
         return stats
+
+    def _round(self, stats: InferStats, trees: DerivationTrees,
+               active: set, pool: "ThreadPoolExecutor | None",
+               infer_id: int) -> bool:
+        """One fixpoint round: plan, evaluate and write every level's
+        changed rules.  Returns whether any table changed."""
+        cfg = self.config
+        rnd = stats.iterations
+        changed = False
+
+        def plan_rule(r: int) -> tuple | None:
+            with span("hf.plan", infer=infer_id, round=rnd,
+                      rule=self.rules[r].name) as sp:
+                plan = self._begin_rule_eval(r)
+                sp.set_metadata(plan=plan[0] if plan else "full",
+                                passes=_plan_passes(plan))
+            return plan
+
+        def eval_rule(r: int, plan: tuple | None) -> tuple:
+            with span("hf.rule", infer=infer_id, round=rnd,
+                      rule=self.rules[r].name, passes=_plan_passes(plan)):
+                return self._eval_one(r, plan)
+
+        # deaths since the last round (or from deletes between infer
+        # calls) that signed frontiers cannot absorb trigger the DRed
+        # scrub before the round's evaluations
+        with span("hf.plan", infer=infer_id, round=rnd, plan="deaths"):
+            changed = self._check_death_frontiers(stats)
+        round_rows = 0
+        round_emitted = 0
+        for level in trees.levels:
+            level_rules = []
+            for r in level:
+                if r not in active:
+                    if not self.rules[r].is_query():
+                        stats.rules_skipped_inactive += 1
+                    continue
+                if self.rules[r].is_query():
+                    continue  # queries run via .query()/.run_queries()
+                if not self._rule_inputs_changed(r):
+                    stats.rules_skipped_unchanged += 1
+                    continue
+                level_rules.append(r)
+            if not level_rules:
+                continue
+            # Algorithm 2: islands + sort keys rebuilt per level
+            # (cardinalities moved); groups own disjoint output types.
+            groups = trees.out_groups(level_rules, set(level_rules))
+            results: list[tuple[int, dict, dict, dict, dict]] = []
+            if pool is not None and cfg.tree_exec == "PF" and len(groups) > 1:
+                futs = [pool.submit(eval_rule, r, plan_rule(r))
+                        for g in groups for r in g]
+                results = [f.result() for f in futs]
+            else:
+                for g in groups:
+                    for r in g:
+                        results.append(eval_rule(r, plan_rule(r)))
+            stats.rules_evaluated += len(results)
+            for _, _, _, _, es in results:
+                round_rows += es.get("rows_considered", 0)
+                stats.delta_passes += es.get("delta_passes", 0)
+                stats.full_evals += es.get("full_evals", 0)
+                stats.neg_passes += es.get("neg_passes", 0)
+                stats.replans += es.get("replans", 0)
+            # Writes: PW = concurrent per disjoint fact type;
+            # SW = sequential in schedule order.  Set-semantics
+            # adds (full fallbacks), explicit deletes, then the
+            # signed counting application.
+            by_type_adds: dict[str, list] = {}
+            by_type_dels: dict[str, list] = {}
+            by_type_signed: dict[str, list] = {}
+            for _, adds, dels, signed, _es in results:
+                for t, cols in adds.items():
+                    by_type_adds.setdefault(t, []).append(cols)
+                for t, cols in dels.items():
+                    by_type_dels.setdefault(t, []).append(cols)
+                for t, batches in signed.items():
+                    by_type_signed.setdefault(t, []).extend(batches)
+
+            def _write_type(t: str, parts: list) -> int:
+                return self._insert_columns(
+                    t, *self._cat_parts(parts), asserted=False)
+
+            if pool is not None and cfg.index_write == "PW" and len(by_type_adds) > 1:
+                futs = {t: pool.submit(_write_type, t, p)
+                        for t, p in by_type_adds.items()}
+                wrote = {t: f.result() for t, f in futs.items()}
+            else:
+                wrote = {t: _write_type(t, p)
+                         for t, p in by_type_adds.items()}
+            for t, parts in by_type_dels.items():
+                cols = self._cat_parts(parts)
+                ndel = self._delete_matching(t, cols[0], cols[1], cols[2])
+                stats.facts_deleted += ndel
+                changed |= ndel > 0
+            for t, batches in by_type_signed.items():
+                with span("hf.write", type=t,
+                          rows_in=sum(len(c[0]) for _, c in batches)) as sp:
+                    cnt = self._signed_counts(batches)
+                    nn, nd = (0, 0) if cnt is None else self._apply_counts(
+                        t, *cnt)
+                    sp.set_metadata(rows_fresh=nn)
+                stats.facts_inferred += nn
+                stats.facts_retracted += nd
+                round_emitted += nn
+                changed |= (nn + nd) > 0
+            n_new = sum(wrote.values())
+            stats.facts_inferred += n_new
+            round_emitted += n_new
+            changed |= n_new > 0
+        stats.rows_considered += round_rows
+        stats.rows_emitted += round_emitted
+        stats.rounds.append({"iteration": stats.iterations,
+                             "rows_considered": round_rows,
+                             "rows_emitted": round_emitted})
+        return changed
 
     # ------------------------------------------------- sketch planner
     def _sketch_planner(self):

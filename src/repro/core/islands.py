@@ -27,6 +27,7 @@ from repro.core.conditions import Condition, Rule, bindings_for_rows, ccar, rl
 from repro.core.joins import (Bindings, ColumnarBindings, dedup_bindings,
                               join_bindings, make_bindings, semi_join_rows)
 from repro.core.store import Component, FactStore
+from repro.tracing import span
 
 # ---------------------------------------------------------------------------
 # Sort keys
@@ -108,6 +109,11 @@ def _island_key(c: Condition, i: int) -> str:
 def build_islands(store: FactStore, rule: Rule) -> list[Island]:
     """Phases 1+2 of Algorithm 1: per-condition stats, grouping by id-var,
     island cost aggregation (Eq. 1)."""
+    with span("hf.islands", rule=rule.name):
+        return _build_islands(store, rule)
+
+
+def _build_islands(store: FactStore, rule: Rule) -> list[Island]:
     conds = list(rule.conditions)
     all_vars = [set(c.variables().keys()) for c in conds]
     stats: list[CondStats] = []
@@ -329,26 +335,16 @@ def _evaluate_adaptive(store: FactStore, rule: Rule, islands: list[Island],
     pending = [(t, c.valtype) for c in rule.conditions for t in c.tests]
     acc: Bindings | None = None
     bound: set[str] = set()
-    plan = _plan_order(planner, store, joins, bound, None)
+    with span("hf.islands", rule=rule.name):
+        plan = _plan_order(planner, store, joins, bound, None)
     replans = 0
     while plan:
         st, pred = plan.pop(0)
-        rhs = _lookup_condition(store, st.cond, acc, rnl_mode, layout,
-                                rl_fn, ops, pipeline, 0, stats)
-        if acc is None:
-            acc = rhs
-        else:
-            keys = [v for v in st.cond.variables() if v in bound]
-            acc = join_bindings(acc, rhs, keys, join_algo, ops)
-        bound |= set(st.cond.variables().keys())
-        still = []
-        for t, vt in pending:
-            if t.var1 in bound and (t.is_const() or t.var2 in bound):
-                if acc.n > 0:
-                    acc = _apply_test(store, acc, t, vt, ops, pipeline)
-            else:
-                still.append((t, vt))
-        pending = still
+        acc, pending = _join_step(store, st, acc, bound, pending,
+                                  join_algo=join_algo, rnl_mode=rnl_mode,
+                                  layout=layout, rl_fn=rl_fn, ops=ops,
+                                  pipeline=pipeline, delta_start=0,
+                                  stats=stats)
         if acc.n == 0:
             return acc
         obs = float(acc.n)
@@ -357,8 +353,9 @@ def _evaluate_adaptive(store: FactStore, rule: Rule, islands: list[Island],
             replans += 1
             if stats is not None:
                 stats["replans"] = stats.get("replans", 0) + 1
-            plan = _plan_order(planner, store, [s for s, _ in plan],
-                               bound, obs)
+            with span("hf.islands", rule=rule.name):
+                plan = _plan_order(planner, store, [s for s, _ in plan],
+                                   bound, obs)
     if acc is None:  # all conditions were existence checks and all passed
         acc = make_bindings({"_exists": np.zeros(1, np.int64)}, layout)
     return dedup_bindings(acc, ops) if distinct else acc
@@ -591,6 +588,37 @@ def _apply_test(store: FactStore, acc: Bindings, t, vt, ops: Ops | None,
     return acc.select(np.nonzero(ok)[0])
 
 
+def _join_step(store: FactStore, st: CondStats, acc: Bindings | None,
+               bound: set[str], pending: list, *, join_algo: str,
+               rnl_mode: str, layout: str, rl_fn, ops: Ops | None,
+               pipeline: bool, delta_start: "int | np.ndarray",
+               stats: dict | None) -> tuple[Bindings, list]:
+    """One step of the island chain: look the condition up, join it into
+    ``acc`` and apply every join test whose operands are now bound.
+    Adds the condition's variables to ``bound``; returns the new
+    accumulator and the tests still pending."""
+    stats = {} if stats is None else stats
+    before = stats.get("rows_considered", 0)
+    with span("hf.join") as sp:
+        rhs = _lookup_condition(store, st.cond, acc, rnl_mode, layout,
+                                rl_fn, ops, pipeline, delta_start, stats)
+        if acc is None:
+            acc = rhs
+        else:
+            keys = [v for v in st.cond.variables() if v in bound]
+            acc = join_bindings(acc, rhs, keys, join_algo, ops)
+        bound |= set(st.cond.variables().keys())
+        still = []
+        for t, vt in pending:
+            if t.var1 in bound and (t.is_const() or t.var2 in bound):
+                if acc.n > 0:
+                    acc = _apply_test(store, acc, t, vt, ops, pipeline)
+            else:
+                still.append((t, vt))
+        sp.set_metadata(rows=stats.get("rows_considered", 0) - before)
+    return acc, still
+
+
 def evaluate_rule(store: FactStore, rule: Rule, *, join_algo: str = "MJ",
                   rnl_mode: str = "AR", layout: str = "CR",
                   sort_mode: str = "sortkeys", distinct: bool = False,
@@ -638,14 +666,17 @@ def evaluate_rule(store: FactStore, rule: Rule, *, join_algo: str = "MJ",
             layout=layout, distinct=distinct, rl_fn=rl_fn, ops=ops,
             pipeline=pipeline, stats=stats, planner=planner)
     prefer = set(delta_for) if delta_for else None
-    ordered = order_islands(islands, prefer)
+    with span("hf.islands", rule=rule.name):
+        ordered = order_islands(islands, prefer)
     # A join test (Def. 9) fires as soon as its operands are bound (the
     # var⊕const form needs only its left variable).
     pending = [(t, c.valtype) for c in rule.conditions for t in c.tests]
     acc: Bindings | None = None
     bound: set[str] = set()
     for isl in ordered:
-        for st in order_conditions(isl, bound, sort_mode, prefer):
+        with span("hf.islands", rule=rule.name):
+            conds = order_conditions(isl, bound, sort_mode, prefer)
+        for st in conds:
             ds = delta_for.get(st.index, 0) if delta_for else 0
             if not st.cond.variables():
                 # variable-free (rank-3) condition == existence filter
@@ -661,22 +692,11 @@ def evaluate_rule(store: FactStore, rule: Rule, *, join_algo: str = "MJ",
                         {v: np.empty(0, np.int64) for v in bound} or
                         {"_exists": np.empty(0, np.int64)}, layout)
                 continue
-            rhs = _lookup_condition(store, st.cond, acc, rnl_mode, layout,
-                                    rl_fn, ops, pipeline, ds, stats)
-            if acc is None:
-                acc = rhs
-            else:
-                keys = [v for v in st.cond.variables() if v in bound]
-                acc = join_bindings(acc, rhs, keys, join_algo, ops)
-            bound |= set(st.cond.variables().keys())
-            still = []
-            for t, vt in pending:
-                if t.var1 in bound and (t.is_const() or t.var2 in bound):
-                    if acc.n > 0:
-                        acc = _apply_test(store, acc, t, vt, ops, pipeline)
-                else:
-                    still.append((t, vt))
-            pending = still
+            acc, pending = _join_step(store, st, acc, bound, pending,
+                                      join_algo=join_algo, rnl_mode=rnl_mode,
+                                      layout=layout, rl_fn=rl_fn, ops=ops,
+                                      pipeline=pipeline, delta_start=ds,
+                                      stats=stats)
             if acc.n == 0:
                 return acc
     if acc is None:  # all conditions were existence checks and all passed

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.backend import Ops, get_backend, splitmix64  # noqa: F401  (re-export)
 from repro.core.facts import StringDictionary
+from repro.tracing import span
 
 PAGE_ROWS = 4096  # paper: pages pre-allocated by a memory pool
 
@@ -104,7 +105,9 @@ class Rank1Index(abc.ABC):
                   "alive": table.alive if table.n_dead else None,
                   "hint": {int(Component.ATTR): "dict",
                            int(Component.ID): "for"}.get(int(comp))}
-        skeys, perm = self.ops.sort_perm(col, **kw)
+        with span("hf.index", table=getattr(table, "ftype", ""),
+                  mode="rebuild", rows=len(col)):
+            skeys, perm = self.ops.sort_perm(col, **kw)
         return skeys.astype(col.dtype, copy=False), perm.astype(np.int32)
 
     @abc.abstractmethod
@@ -547,7 +550,8 @@ class TypedFactTable:
         self.n = start + m
         self.version += 1  # before the index build: it caches under the
         self.data_version += 1
-        self.index.append(self, start, self.n)  # post-append version
+        with span("hf.index", table=self.ftype, mode="append", rows=m):
+            self.index.append(self, start, self.n)  # post-append version
         return m
 
     def contains(self, iid: int, attr: int, val: int) -> bool:

@@ -1,0 +1,129 @@
+"""Spans of the fact engine (``repro.tracing``) in a profiler trace.
+
+A small RDFS-Plus materialization runs under ``jax.profiler``: every
+span the closure path reaches shows up with its arguments, the transfer
+spans account for every host<->device byte the backend counts, and
+tracing leaves the fact set unchanged.
+"""
+
+import dataclasses
+import glob
+import os
+
+import pytest
+
+from repro.core import EngineConfig, Fact, HiperfactEngine
+from repro.core.rulesets import rdfs_plus_rules
+from repro.tracing import SPANS
+
+TRANSFER_SPANS = {"hf.d2h", "hf.h2d"}
+
+
+def kg_facts():
+    facts = [
+        Fact("Schema", "A", "subClassOf", "B"),
+        Fact("Schema", "B", "subClassOf", "C"),
+        Fact("Schema", "C", "subClassOf", "D"),
+        Fact("Schema", "knows", "characteristic", "symmetric"),
+        Fact("Schema", "partOf", "characteristic", "transitive"),
+        Fact("Schema", "worksFor", "subPropertyOf", "memberOf"),
+        Fact("Data", "y", "type", "B"),
+        Fact("Data", "x", "knows", "y"),
+    ]
+    facts += [Fact("Data", f"p{i}", "partOf", f"p{i + 1}") for i in range(6)]
+    facts += [Fact("Data", f"s{i}", "worksFor", f"d{i % 3}")
+              for i in range(12)]
+    facts += [Fact("Data", f"s{i}", "type", "A") for i in range(12)]
+    return facts
+
+
+def engine(backend):
+    e = HiperfactEngine(dataclasses.replace(EngineConfig.infer1(backend),
+                                            eval_mode="delta"))
+    e.add_rules(rdfs_plus_rules())
+    return e
+
+
+def fact_set(e):
+    s = e.store.strings
+    return {(ftype, s.lookup_id(int(t.ids[i])), s.lookup_id(int(t.attrs[i])),
+             int(t.vals[i]))
+            for ftype, t in e.store.tables.items()
+            for i in range(t.n) if t.alive[i]}
+
+
+def hf_events(trace_dir):
+    """``(name, stats)`` of every ``hf.*`` host event in the trace."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    return [(e.name, dict(e.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("hf.")]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax-interpret"])
+def test_materialization_spans(backend, tmp_path):
+    import jax
+
+    e0 = engine(backend)
+    e0.insert_facts(kg_facts())
+    e0.infer()
+    untraced = fact_set(e0)
+
+    e = engine(backend)
+    counter = getattr(e.ops, "transfers", None)
+    before = counter.snapshot() if counter is not None else None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        e.insert_facts(kg_facts())
+        st = e.infer()
+    finally:
+        jax.profiler.stop_trace()
+    events = hf_events(str(tmp_path))
+
+    assert fact_set(e) == untraced
+    names = {n for n, _ in events}
+    reachable = set(SPANS) - (TRANSFER_SPANS if counter is None else set())
+    assert names == reachable, sorted(reachable ^ names)
+
+    # one plan span per round checks the death frontiers; the others
+    # plan one rule each
+    per_rule = [(n, a) for n, a in events if n == "hf.rule"
+                or (n == "hf.plan" and a["plan"] != "deaths")]
+    assert per_rule
+    for name, args in per_rule:
+        assert {"round", "rule", "infer"} <= set(args), (name, args)
+        assert 1 <= args["round"] <= st.iterations
+    rounds = sorted(a["round"] for n, a in events if n == "hf.round")
+    assert rounds == list(range(1, st.iterations + 1))
+    plans = {a["plan"] for n, a in events if n == "hf.plan"}
+    assert {"init", "delta"} <= plans, plans
+    writes = [a for n, a in events if n == "hf.write"]
+    assert writes and all("rows_fresh" in a for a in writes)
+
+    d2h = [a["bytes"] for n, a in events if n == "hf.d2h"]
+    if counter is not None:
+        delta = counter.delta(before)
+        assert len(d2h) == delta.d2h_calls > 0
+        assert sum(d2h) == delta.d2h_bytes
+        h2d = [a["bytes"] for n, a in events if n == "hf.h2d"]
+        assert len(h2d) == delta.h2d_calls
+        assert sum(h2d) == delta.h2d_bytes
+    else:
+        assert d2h == []
+
+
+def test_span_costs_little_without_a_profiler():
+    """With no profiler running a span is a cheap no-op context."""
+    import time
+
+    from repro.tracing import span
+    n = 20000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with span("hf.join", rows=i) as sp:
+            sp.set_metadata(rows=i)
+    assert (time.perf_counter() - t0) / n < 50e-6
