@@ -70,6 +70,9 @@ class Ops(abc.ABC):
     """
 
     name: str = "?"
+    # where ``match_rows`` looks rows up ("host" for this twin): the
+    # engine's counting-path write spans count the rows of each
+    match_where: str = "host"
 
     # -- primitives -------------------------------------------------------
     @abc.abstractmethod
@@ -383,16 +386,31 @@ class Ops(abc.ABC):
         residency; host backends ignore the hint.  Callers are
         responsible for tombstone handling (the engine falls back to the
         host path when the table has dead rows)."""
-        kn = key_new.host()
-        vn = vals_new.host()
-        exists = np.zeros(key_new.n, bool)
-        if len(old_keys) and key_new.n:
-            li, ri = self.join_pairs(kn, old_keys)
-            if len(li):
-                ok = vn[li] == old_vals[ri]
-                exists[li[ok]] = True
-        fresh = ~exists
+        fresh = self.match_rows(key_new.host(), vals_new.host(), old_keys,
+                                old_vals) < 0
         return DeviceCol(fresh, key_new.n, self, host=fresh)
+
+    def match_rows(self, key_new: np.ndarray, vals_new: np.ndarray,
+                   old_keys: np.ndarray, old_vals: np.ndarray,
+                   cache_uid=None, version: int | None = None
+                   ) -> np.ndarray:
+        """Write-side row lookup: for each probe row ``(key_new[i],
+        vals_new[i])``, the index of the *last* table row whose ``(key,
+        val)`` equals it, or -1 (int64).  The last copy of a triple is
+        the only one that can be alive (the engine revives no row and
+        inserts a triple only when no alive copy exists); the caller
+        checks ``alive``.  ``cache_uid``/``version`` identify the
+        append-only table columns for device residency, as for
+        ``fresh_mask_h``; host backends ignore the hint."""
+        key_new = np.asarray(key_new, np.int64)
+        rowof = np.full(len(key_new), -1, np.int64)
+        if len(key_new) == 0 or len(old_keys) == 0:
+            return rowof
+        li, ri = self.join_pairs(key_new, np.asarray(old_keys, np.int64))
+        ok = (np.asarray(vals_new, np.int64)[li]
+              == np.asarray(old_vals, np.int64)[ri])
+        np.maximum.at(rowof, li[ok], ri[ok])
+        return rowof
 
     def batch_probe(self, sorted_keys: np.ndarray, probes: np.ndarray, *,
                     cache_key=None, version: int | None = None

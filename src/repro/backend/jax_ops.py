@@ -180,37 +180,53 @@ def _jitted():
         return (a << 32) | (b & 0xFFFFFFFF)
 
     @functools.partial(jax.jit, static_argnames=())
-    def sort_pairs_xla(keys, vals, n_real):
-        """(key, val) rows sorted lexicographically, pads (flag-based)
-        last — the probe structure for the write-side exists check."""
-        cap = keys.shape[0]
-        lane = jnp.arange(cap, dtype=jnp.int64)
-        is_pad = lane >= n_real
-        order = jnp.lexsort((vals, keys, is_pad))
+    def pad_pairs(keys, vals, n_real):
+        """(key, val) rows with both lanes of every pad at int64 max, so
+        pads sort after every real row, and each lane's row (int32)."""
+        lane = jnp.arange(keys.shape[0], dtype=jnp.int32)
+        real = lane < n_real
         mx = jnp.iinfo(jnp.int64).max
-        ks = jnp.where(lane < n_real, keys[order], mx)
-        vs = jnp.where(lane < n_real, vals[order], mx)
-        return ks, vs
+        return jnp.where(real, keys, mx), jnp.where(real, vals, mx), lane
+
+    def last_equal_pair(ks, vs, n_old, kn, vn):
+        """For each (kn, vn) row: the last lane of the sorted (ks, vs)
+        rows that holds exactly that pair, and whether there is one — a
+        branch-free upper-bound search in (key, val) order (no pair
+        expansion, so no output capacity to retry).  The search is a
+        ``fori_loop``: unrolled, with ``searchsorted`` for the key run,
+        the program compiled for a TPU v5e to 28 MB of code per shape,
+        which sits in device memory."""
+        cap_old = ks.shape[0]
+
+        def step(_, bounds):
+            lo, hi = bounds
+            active = lo < hi
+            mid = (lo + hi) // 2
+            at = jnp.clip(mid, 0, cap_old - 1)
+            k, v = ks[at], vs[at]
+            go = (k < kn) | ((k == kn) & (v <= vn))
+            return (jnp.where(active & go, mid + 1, lo),
+                    jnp.where(active & ~go, mid, hi))
+
+        lo, _ = jax.lax.fori_loop(
+            0, cap_old.bit_length() + 1, step,
+            (jnp.zeros(kn.shape, jnp.int64),
+             jnp.full(kn.shape, n_old, jnp.int64)))
+        last = jnp.clip(lo - 1, 0, cap_old - 1)
+        return last, (lo > 0) & (ks[last] == kn) & (vs[last] == vn)
 
     @functools.partial(jax.jit, static_argnames=())
     def fresh_pairs(ks, vs, n_old, kn, vn):
         """For each (kn, vn) row: True iff the pair does NOT appear in
-        the sorted (ks, vs) rows — a branch-free binary search of ``vn``
-        inside each key's run (the write-side anti-join, no pair
-        expansion and therefore no output-capacity retry loop)."""
-        cap_old = ks.shape[0]
-        klo = jnp.minimum(jnp.searchsorted(ks, kn, side="left"), n_old)
-        khi = jnp.minimum(jnp.searchsorted(ks, kn, side="right"), n_old)
-        lo, hi = klo, khi
-        for _ in range(max(1, cap_old.bit_length()) + 1):
-            active = lo < hi
-            mid = (lo + hi) // 2
-            v = vs[jnp.clip(mid, 0, cap_old - 1)]
-            go = v < vn
-            lo = jnp.where(active & go, mid + 1, lo)
-            hi = jnp.where(active & ~go, mid, hi)
-        found = (lo < khi) & (vs[jnp.clip(lo, 0, cap_old - 1)] == vn)
-        return ~found
+        the sorted (ks, vs) rows (the write-side anti-join)."""
+        return ~last_equal_pair(ks, vs, n_old, kn, vn)[1]
+
+    @functools.partial(jax.jit, static_argnames=())
+    def match_pairs(ks, vs, perm, n_old, kn, vn):
+        """For each (kn, vn) row: the table row of the last sorted lane
+        holding exactly that pair, or -1 (int32)."""
+        last, found = last_equal_pair(ks, vs, n_old, kn, vn)
+        return jnp.where(found, perm[last], -1)
 
     @functools.partial(
         jax.jit, static_argnames=("block", "use_pallas", "interpret"))
@@ -389,7 +405,8 @@ def _jitted():
             "extend_buffer": extend_buffer, "semi_join_n": semi_join_n,
             "repad_max": repad_max, "member_sorted_n": member_sorted_n,
             "gather_clip": gather_clip, "pack_pairs": pack_pairs,
-            "sort_pairs_xla": sort_pairs_xla, "fresh_pairs": fresh_pairs,
+            "pad_pairs": pad_pairs, "fresh_pairs": fresh_pairs,
+            "match_pairs": match_pairs,
             "batch_probe_j": batch_probe_j, "test_mask": test_mask,
             "cross_gather": cross_gather, "widen": widen,
             "decode_for": decode_for, "decode_for_n": decode_for_n,
@@ -410,6 +427,8 @@ class JaxOps(Ops):
     # full re-sort re-establishes the baseline (bounds re-base drift and
     # keeps the tagged run's merge history shallow)
     MIRROR_COMPACT_RUNS = 64
+
+    match_where = "device"
 
     def __init__(self, mode: str = "auto", block: int = 1024,
                  min_bucket: int | None = None,
@@ -1707,6 +1726,56 @@ class JaxOps(Ops):
             return self._memo_put(key, (h, kept), rows.nbytes)
         return h, kept
 
+    def _pair_mirror(self, old_keys: np.ndarray, old_vals: np.ndarray,
+                     cache_uid, version: "int | None", keep: bool) -> dict:
+        """The table's ``(key, val)`` rows sorted on device, with the
+        table row of each sorted lane: ``{"ks", "vs", "perm", "n"}``.
+        Cached under ``("pkv", uid)`` at the table's version when
+        ``keep``; read from there by every caller.  Caller holds the
+        lock and the x64 scope."""
+        from repro.kernels.sortmerge.ops import device_sort_kv
+        use_cache = cache_uid is not None and version is not None
+        pkv = (self.cache.get(("pkv", cache_uid), version)
+               if use_cache else None)
+        if pkv is not None:
+            return pkv
+        jt = _jitted()
+        n = len(old_keys)
+        if use_cache:
+            # encode=False governs a *cold build* only: the probe side
+            # arrives raw, so a fresh upload must stay raw too.  But the
+            # ("pk", uid) entry is shared with ``join_pairs`` (the
+            # engine's retraction joins), which dict-codes it under
+            # compression — a hit or an append-extend of that entry
+            # comes back *coded*, so decode to raw on device before
+            # sorting.
+            kb = self._resident_column(("pk", cache_uid), version,
+                                       old_keys, INT64_MIN, encode=False)
+            vb = self._resident_column(("vals", cache_uid), version,
+                                       old_vals, 0, encode=False)
+            kraw = self._raw_colbuf(kb, old_keys, INT64_MIN)
+            vraw = self._raw_colbuf(vb, old_vals, 0)
+            cap_o = max(kraw.shape[0], vraw.shape[0])
+            kbuf = self._fit_cap(kraw, cap_o)
+            vbuf = self._fit_cap(vraw, cap_o)
+        else:
+            cap_o = self._bucket(n)
+            kbuf = self._to_dev(self._pad(old_keys, cap_o, INT64_MIN))
+            vbuf = self._to_dev(self._pad(old_vals, cap_o, 0))
+        # two stable passes, by val then by key, on the shared XLA key
+        # sort: called without this backend's Pallas flags in every
+        # mode, since the Pallas sort is not stable.  A lexsort program
+        # of its own compiled for a TPU v5e to 2.8 MB of code per
+        # capacity, which sits in device memory.
+        k, v, lane = jt["pad_pairs"](kbuf, vbuf, n)
+        _, by_val = device_sort_kv(v, lane)
+        ks, perm = device_sort_kv(jt["gather"](k, by_val), by_val)
+        pkv = {"ks": ks, "vs": jt["gather"](v, perm), "perm": perm, "n": n}
+        if use_cache and keep:
+            self.cache.put(("pkv", cache_uid), version, pkv,
+                           2 * ks.nbytes + perm.nbytes)
+        return pkv
+
     def fresh_mask_h(self, key_new: DeviceCol, vals_new: DeviceCol,
                      old_keys, old_vals, cache_uid=None,
                      version: int | None = None) -> DeviceCol:
@@ -1724,48 +1793,15 @@ class JaxOps(Ops):
             if hit is not None:
                 return hit
         import jax.numpy as jnp
-        jt = _jitted()
         old_keys = np.asarray(old_keys, np.int64)
         old_vals = np.asarray(old_vals, np.int64)
         with self._lock, self._x64():
             if len(old_keys) == 0:
                 buf = jnp.ones(key_new.data.shape[0], bool)
             else:
-                pkv = (self.cache.get(("pkv", cache_uid), version)
-                       if use_cache else None)
-                if pkv is None:
-                    if use_cache:
-                        # encode=False governs a *cold build* only: the
-                        # probe side below arrives raw, so a fresh
-                        # upload must stay raw too.  But the ("pk", uid)
-                        # entry is shared with ``join_pairs`` (engine
-                        # dedup / retraction joins), which dict-codes it
-                        # under compression — a hit or an append-extend
-                        # of that entry comes back *coded*, so decode to
-                        # raw on device before sorting.
-                        kb = self._resident_column(
-                            ("pk", cache_uid), version, old_keys,
-                            INT64_MIN, encode=False)
-                        vb = self._resident_column(
-                            ("vals", cache_uid), version, old_vals, 0,
-                            encode=False)
-                        kraw = self._raw_colbuf(kb, old_keys, INT64_MIN)
-                        vraw = self._raw_colbuf(vb, old_vals, 0)
-                        cap_o = max(kraw.shape[0], vraw.shape[0])
-                        kbuf = self._fit_cap(kraw, cap_o)
-                        vbuf = self._fit_cap(vraw, cap_o)
-                    else:
-                        cap_o = self._bucket(len(old_keys))
-                        kbuf = self._to_dev(
-                            self._pad(old_keys, cap_o, INT64_MIN))
-                        vbuf = self._to_dev(self._pad(old_vals, cap_o, 0))
-                    ks, vs = jt["sort_pairs_xla"](kbuf, vbuf,
-                                                  len(old_keys))
-                    pkv = {"ks": ks, "vs": vs, "n": len(old_keys)}
-                    if use_cache:
-                        self.cache.put(("pkv", cache_uid), version, pkv,
-                                       ks.nbytes + vs.nbytes)
-                buf = jt["fresh_pairs"](
+                pkv = self._pair_mirror(old_keys, old_vals, cache_uid,
+                                        version, keep=True)
+                buf = _jitted()["fresh_pairs"](
                     pkv["ks"], pkv["vs"], pkv["n"], key_new.data,
                     self._fit_cap(vals_new.data,
                                   key_new.data.shape[0]))
@@ -1773,6 +1809,30 @@ class JaxOps(Ops):
         if memo:
             self._memo_put(key, h, buf.nbytes)
         return h
+
+    def match_rows(self, key_new, vals_new, old_keys, old_vals,
+                   cache_uid=None, version: int | None = None
+                   ) -> np.ndarray:
+        key_new = np.asarray(key_new, np.int64)
+        n = len(key_new)
+        if n == 0 or len(old_keys) == 0:
+            return np.full(n, -1, np.int64)
+        vals_new = np.asarray(vals_new, np.int64)
+        old_keys = np.asarray(old_keys, np.int64)
+        old_vals = np.asarray(old_vals, np.int64)
+        cap = self._bucket(n)
+        with self._lock, self._x64():
+            # the counting path probes each table version once (its own
+            # writes move the version), so its mirror is not kept
+            pkv = self._pair_mirror(old_keys, old_vals, cache_uid, version,
+                                    keep=False)
+            kn = self._to_dev(self._pad(key_new, cap, 0))
+            vn = self._narrow_h2d(vals_new, cap, 0, int(vals_new.min()),
+                                  int(vals_new.max()))
+            rows = _jitted()["match_pairs"](
+                pkv["ks"], pkv["vs"], pkv["perm"], pkv["n"], kn, vn)
+            out = self._prefix_to_host(rows, n)
+        return out.astype(np.int64)
 
     def residency_stats(self) -> dict:
         """Footprint report for the compressed-resident tier: actual

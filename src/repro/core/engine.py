@@ -14,6 +14,7 @@ index backend (AI/HI/LPIM/LPID) × join (HJ/MJ) × RNL (AR/DR) × result layout
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import time
@@ -218,36 +219,31 @@ class _PackedKeyMemo:
 
 def _match_rows(table: TypedFactTable, ids: np.ndarray, attrs: np.ndarray,
                 vals: np.ndarray, ops: Ops | None = None,
-                pk_memo: _PackedKeyMemo | None = None) -> np.ndarray:
-    """SU-path bulk lookup against the table: vectorized sorted join on
-    the packed (id, attr) key with exact val verification.  Returns, per
-    batch row, the matching *alive* table row id (or -1): the write side
-    uses it both as the dedup mask and as the target for support /
-    asserted maintenance."""
-    rowof = np.full(len(ids), -1, np.int64)
+                pk_memo: _PackedKeyMemo | None = None,
+                tally: collections.Counter | None = None) -> np.ndarray:
+    """SU-path bulk lookup against the table by the whole (id, attr, val)
+    row (``Ops.match_rows``).  Returns, per batch row, the matching
+    *alive* table row id (or -1): the write side uses it both as the
+    dedup mask and as the target for support / asserted maintenance.
+    The newest copy of a triple is the only one that can be alive (rows
+    never revive, and a triple is inserted only when no alive copy
+    exists), so the lookup's last equal row is the one to check.
+    ``tally`` counts the rows looked up by ``ops.match_where``."""
     if table.n == 0 or len(ids) == 0:
-        return rowof
+        return np.full(len(ids), -1, np.int64)
     ops = ops or get_backend("numpy")
-    key_new = _pack_keys(ids, attrs)
     if pk_memo is not None:
         key_old = pk_memo.keys_for(table)
     else:
         key_old = _pack_keys(table.ids, table.attrs)
-    li, ri = ops.join_pairs(key_new, key_old,
-                            rkeys_key=("pk", table.uid),
-                            rkeys_version=table.version)
-    if len(li) == 0:
-        return rowof
-    ok = (vals[li] == table.vals[ri]) & table.alive[ri]
-    rowof[li[ok]] = ri[ok]
+    rowof = ops.match_rows(_pack_keys(ids, attrs), vals, key_old,
+                           table.vals, cache_uid=table.uid,
+                           version=table.version)
+    if tally is not None:
+        tally[ops.match_where] += len(ids)
+    hit = np.flatnonzero(rowof >= 0)
+    rowof[hit[~table.alive[rowof[hit]]]] = -1
     return rowof
-
-
-def _mask_existing(table: TypedFactTable, ids: np.ndarray, attrs: np.ndarray,
-                   vals: np.ndarray, ops: Ops | None = None,
-                   pk_memo: _PackedKeyMemo | None = None) -> np.ndarray:
-    """SU-path bulk dedup against the table (see ``_match_rows``)."""
-    return _match_rows(table, ids, attrs, vals, ops, pk_memo) >= 0
 
 
 def _resolve_shards(config: EngineConfig) -> int:
@@ -311,6 +307,8 @@ class HiperfactEngine:
         self._n_compensated = 0
         self._comp_reported = 0
         self._pk_memo = _PackedKeyMemo()
+        # rows the counting path looked up, by where ("device"/"host")
+        self._lookups: collections.Counter = collections.Counter()
         self._n_infer = 0  # infer() calls: the id its spans share
         self.last_infer: InferStats = InferStats()
         from repro.core.querycache import QueryResultCache, RankNCache
@@ -942,7 +940,8 @@ class HiperfactEngine:
         retract support — a fact whose support collapses to zero with no
         assertion left dies and enters the delete log."""
         table = self.store.table(ftype)
-        rowof = _match_rows(table, ids, attrs, vals, self.ops, self._pk_memo)
+        rowof = _match_rows(table, ids, attrs, vals, self.ops, self._pk_memo,
+                            self._lookups)
         hit = rowof >= 0
         n_new = n_dead = 0
         pos = hit & (net > 0)
@@ -1177,10 +1176,13 @@ class HiperfactEngine:
             for t, batches in by_type_signed.items():
                 with span("hf.write", type=t,
                           rows_in=sum(len(c[0]) for _, c in batches)) as sp:
+                    self._lookups.clear()
                     cnt = self._signed_counts(batches)
                     nn, nd = (0, 0) if cnt is None else self._apply_counts(
                         t, *cnt)
-                    sp.set_metadata(rows_fresh=nn)
+                    sp.set_metadata(rows_fresh=nn,
+                                    match_rows=self._lookups["device"],
+                                    match_host=self._lookups["host"])
                 stats.facts_inferred += nn
                 stats.facts_retracted += nd
                 round_emitted += nn

@@ -13,6 +13,7 @@ an unchanged table version and delta-only uploads on append.
 """
 
 import dataclasses
+import itertools
 
 import numpy as np
 import pytest
@@ -532,6 +533,187 @@ def test_handle_memo_repeat_is_free():
     _ = lout2[0].host()
     d = ops.transfers.delta(snap)
     assert d.h2d_calls == 0 and d.d2h_calls == 0, d
+
+
+# ---------------------------------------------------------------------------
+# Write-side row lookup: the last table row holding each (key, val)
+
+
+def last_equal_rows(old_k, old_v, new_k, new_v):
+    """Brute-force oracle of ``match_rows``."""
+    want = np.full(len(new_k), -1, np.int64)
+    for i, (k, v) in enumerate(zip(new_k.tolist(), new_v.tolist())):
+        hit = np.flatnonzero((old_k == k) & (old_v == v))
+        if len(hit):
+            want[i] = hit[-1]
+    return want
+
+
+MATCH_UIDS = itertools.count()
+
+
+def check_match_rows(ops, old_k, old_v, new_k, new_v, uid=None,
+                     version=1):
+    # a (uid, version) names one table state for the whole process
+    uid = ("match", next(MATCH_UIDS)) if uid is None else uid
+    got = ops.match_rows(new_k, new_v, old_k, old_v, cache_uid=uid,
+                         version=version)
+    want = HOST.match_rows(new_k, new_v, old_k, old_v)
+    assert got.dtype == want.dtype == np.int64
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(want, last_equal_rows(old_k, old_v,
+                                                        new_k, new_v))
+    return got
+
+
+def tc_table(n_vals=249):
+    """One (id, attr) key with ``n_vals`` values, as every ``path(x, to,
+    ?)`` fact of a tc closure, beside a few other keys."""
+    k = np.concatenate([np.full(n_vals, 5 << 32 | 3, np.int64),
+                        RNG.randint(0, 9, 60).astype(np.int64) << 32])
+    v = np.concatenate([RNG.permutation(n_vals).astype(np.int64),
+                        RNG.randint(0, 40, 60).astype(np.int64)])
+    order = RNG.permutation(len(k))
+    return k[order], v[order]
+
+
+def match_tc_runs(ops):
+    old_k, old_v = tc_table()
+    new_v = np.arange(0, 2 * 249, 2, dtype=np.int64)  # half present
+    new_k = np.full(len(new_v), 5 << 32 | 3, np.int64)
+    got = check_match_rows(ops, old_k, old_v, new_k, new_v)
+    assert (got >= 0).sum() == 125
+
+
+def match_random(ops):
+    old_k = RNG.randint(-30, 30, 700).astype(np.int64) * (1 << 33)
+    old_v = RNG.randint(0, 12, 700).astype(np.int64)
+    new_k = RNG.randint(-35, 35, 300).astype(np.int64) * (1 << 33)
+    new_v = RNG.randint(0, 14, 300).astype(np.int64)
+    check_match_rows(ops, old_k, old_v, new_k, new_v)
+
+
+def match_empty_table(ops):
+    e = np.empty(0, np.int64)
+    got = check_match_rows(ops, e, e, np.arange(9, dtype=np.int64),
+                           np.zeros(9, np.int64))
+    assert (got == -1).all()
+
+
+def match_empty_probe(ops):
+    old_k, old_v = tc_table()
+    e = np.empty(0, np.int64)
+    assert len(check_match_rows(ops, old_k, old_v, e, e)) == 0
+
+
+def match_int64_extremes(ops):
+    old_k = np.asarray([INT64_MIN, INT64_MIN, 0, INT64_MAX, INT64_MAX, 7],
+                       np.int64)
+    old_v = np.asarray([INT64_MAX, 0, INT64_MAX, INT64_MAX, INT64_MIN,
+                        INT64_MAX], np.int64)
+    new_k = np.asarray([INT64_MIN, INT64_MIN, INT64_MIN, 0, INT64_MAX,
+                        INT64_MAX, 7, 7], np.int64)
+    new_v = np.asarray([INT64_MAX, 0, 1, INT64_MAX, INT64_MAX, INT64_MIN,
+                        INT64_MIN, INT64_MAX], np.int64)
+    got = check_match_rows(ops, old_k, old_v, new_k, new_v)
+    np.testing.assert_array_equal(got, [0, 1, -1, 2, 3, 4, -1, 5])
+
+
+def match_duplicate_rows(ops):
+    # (key, val) pairs written several times: the answer is the last row
+    old_k = np.asarray([1, 2, 1, 1, 2, 3, 1], np.int64) << 32
+    old_v = np.asarray([9, 4, 9, 8, 4, 0, 9], np.int64)
+    new_k = np.asarray([1, 2, 1, 3, 3], np.int64) << 32
+    new_v = np.asarray([9, 4, 8, 0, 1], np.int64)
+    got = check_match_rows(ops, old_k, old_v, new_k, new_v)
+    np.testing.assert_array_equal(got, [6, 4, 3, 5, -1])
+
+
+def match_coded_pk_entry(ops):
+    """``join_pairs`` dict-codes the shared ``("pk", uid)`` column under
+    compression; the lookup decodes it (hit, then append-extend)."""
+    ops = JaxOps(mode=ops.mode, block=256, compress=True)
+    hub = np.full(60, 1 << 32, np.int64)
+    old_k = np.concatenate([hub, np.arange(2, 62, dtype=np.int64) << 32])
+    old_v = np.arange(120, dtype=np.int64) % 50
+    ops.join_pairs(old_k[:10], old_k, rkeys_key=("pk", 3), rkeys_version=1)
+    entry = ops.cache.get_any(("colbuf", ("pk", 3), INT64_MIN))
+    assert entry.value["codec"] is not None
+    new_k = np.concatenate([old_k[::3], [9 << 32]])
+    new_v = np.concatenate([old_v[::3], [0]])
+    check_match_rows(ops, old_k, old_v, new_k, new_v, uid=3, version=1)
+    more_k = np.concatenate([old_k, old_k[:7]])  # appended duplicates
+    more_v = np.concatenate([old_v, old_v[:7]])
+    got = check_match_rows(ops, more_k, more_v, new_k, new_v, uid=3,
+                           version=2)
+    assert got[0] == 120
+    assert ops.cache.get_any(
+        ("colbuf", ("pk", 3), INT64_MIN)).value["codec"] is not None
+
+
+def match_cache_hit_then_rebuild(ops):
+    """The table's columns stay resident: a repeat at the same version
+    uploads only the probes, and an append uploads only the tail."""
+    ops = JaxOps(mode=ops.mode, block=256)
+    old_k, old_v = tc_table(1000)
+    new_k, new_v = old_k[::20].copy(), old_v[::20].copy()
+    check_match_rows(ops, old_k, old_v, new_k, new_v, uid=4, version=1)
+    hits = ops.cache.hits
+    snap = ops.transfers.snapshot()
+    check_match_rows(ops, old_k, old_v, new_k, new_v, uid=4, version=1)
+    d = ops.transfers.delta(snap)
+    assert d.h2d_calls == 2 and d.d2h_calls == 1, d
+    assert d.h2d_bytes < old_k.nbytes, d
+    assert ops.cache.hits == hits + 2  # the key and value columns
+    more_k = np.concatenate([old_k, new_k[:4]])
+    more_v = np.concatenate([old_v, new_v[:4]])
+    snap = ops.transfers.snapshot()
+    got = check_match_rows(ops, more_k, more_v, new_k, new_v, uid=4,
+                           version=2)
+    d = ops.transfers.delta(snap)
+    np.testing.assert_array_equal(got[:4], len(old_k) + np.arange(4))
+    assert d.h2d_bytes < old_k.nbytes, d
+
+
+def match_shares_fresh_mask_mirror(ops):
+    """``fresh_mask_h`` keeps its sorted mirror under ``("pkv", uid)``,
+    and ``match_rows`` at the same version searches it without sorting;
+    ``match_rows`` keeps none of its own.  The mask stays the host's."""
+    old_k = RNG.randint(0, 40, 400).astype(np.int64)
+    old_v = RNG.randint(0, 3, 400).astype(np.int64)
+    new_k = RNG.randint(0, 50, 90).astype(np.int64)
+    new_v = RNG.randint(0, 3, 90).astype(np.int64)
+    want = HOST.fresh_mask_h(HOST.upload(new_k), HOST.upload(new_v),
+                             old_k, old_v).host()
+    for first in ("fresh", "match"):
+        ops = JaxOps(mode=ops.mode, block=256)
+        if first == "match":
+            rows = check_match_rows(ops, old_k, old_v, new_k, new_v,
+                                    uid=5, version=1)
+            assert ops.cache.get_any(("pkv", 5)) is None
+        got = ops.fresh_mask_h(ops.upload(new_k), ops.upload(new_v),
+                               old_k, old_v, cache_uid=5, version=1)
+        np.testing.assert_array_equal(got.host(), want)
+        mirror = ops.cache.get_any(("pkv", 5)).value
+        if first == "fresh":
+            sorts = ops.route_stats()["xla"]
+            rows = check_match_rows(ops, old_k, old_v, new_k, new_v,
+                                    uid=5, version=1)
+            assert ops.route_stats()["xla"] == sorts
+            assert ops.cache.get_any(("pkv", 5)).value is mirror
+        np.testing.assert_array_equal(rows < 0, want)
+
+
+MATCH_CASES = {f.__name__[len("match_"):].replace("_", "-"): f for f in (
+    match_tc_runs, match_random, match_empty_table, match_empty_probe,
+    match_int64_extremes, match_duplicate_rows, match_coded_pk_entry,
+    match_cache_hit_then_rebuild, match_shares_fresh_mask_mirror)}
+
+
+@pytest.mark.parametrize("case", list(MATCH_CASES))
+@pytest.mark.parametrize("ops", device_backends())
+def test_match_rows_parity(ops, case):
+    MATCH_CASES[case](ops)
 
 
 # ---------------------------------------------------------------------------

@@ -271,9 +271,9 @@ def test_engine_matrix_compressed_parity(join, unique, backend):
 
 def test_write_side_dedup_with_coded_pk_column():
     """Regression: ``join_pairs`` dict-codes the shared ``("pk", uid)``
-    resident column during insert dedup; ``fresh_mask_h`` then hit (or
-    append-extended) that entry and read the narrow *codes* as raw
-    packed keys, so the write-side anti-join reported existing
+    resident column (a retraction join builds it cold); ``fresh_mask_h``
+    then hit (or append-extended) that entry and read the narrow *codes*
+    as raw packed keys, so the write-side anti-join reported existing
     (key, val) pairs as fresh — duplicate rows under compress=True."""
     import dataclasses
     from collections import Counter
@@ -298,8 +298,10 @@ def test_write_side_dedup_with_coded_pk_column():
                                   compress=compress)
         e = HiperfactEngine(cfg)
         e.add_rules(rules)
-        e.insert_facts(batch1)
-        e.insert_facts(batch2)  # _match_rows codes the pk colbuf
+        e.insert_facts(batch1 + batch2)
+        # a retraction that matches no row: its join_pairs builds the
+        # pk colbuf cold and dict-codes it
+        assert e.delete_facts([Fact("Data", "hub", "link", "absent")]) == 0
         e.infer()
         return e
 

@@ -364,3 +364,138 @@ def test_transient_handles_skip_memo():
     g1 = ops.gather_h(stable, idx, 10)
     g2 = ops.gather_h(stable, idx, 10)
     assert g1 is g2
+
+
+# ---------------------------------------------------------------------------
+# The counting write side looks facts up by their whole (id, attr, val)
+
+
+TC_RULES = [
+    Rule("tc-base", (cond("edge", "?x", "to", "?y"),),
+         (AddAction("path", term("?x"), "to", term("?y")),)),
+    Rule("tc-rec", (cond("edge", "?x", "to", "?y"),
+                    cond("path", "?y", "to", "?z")),
+         (AddAction("path", term("?x"), "to", term("?z")),)),
+]
+TWO_HOP_RULES = [
+    Rule("hop2", (cond("edge", "?x", "to", "?y"),
+                  cond("edge", "?y", "to", "?z")),
+         (AddAction("hop2", term("?x"), "to", term("?z")),)),
+]
+CHAIN = [Fact("edge", f"c{i}", "to", f"c{i + 1}") for i in range(5)]
+
+
+def dag_edges(nodes=24, edges=60, seed=3):
+    """A random DAG: distinct pairs oriented low to high."""
+    rng = np.random.RandomState(seed)
+    pairs = set()
+    while len(pairs) < edges:
+        a, b = sorted(rng.choice(nodes, 2, replace=False).tolist())
+        pairs.add((a, b))
+    return [Fact("edge", f"n{a}", "to", f"n{b}") for a, b in sorted(pairs)]
+
+
+def counting_engine(backend, rules):
+    e = HiperfactEngine(dataclasses.replace(EngineConfig.infer1(backend),
+                                            eval_mode="delta"))
+    e.add_rules(rules)
+    return e
+
+
+@pytest.mark.parametrize("ruleset", ["tc", "rdfs-plus"])
+def test_counting_write_side_looks_up_rows_on_device(ruleset, monkeypatch):
+    """On the jax backend the counting path's membership test is
+    ``JaxOps.match_rows``: ``_apply_counts`` never reaches
+    ``join_pairs``, and the fact set is the numpy engine's."""
+    from repro.backend.jax_ops import JaxOps
+    if ruleset == "tc":
+        rules, facts = TC_RULES, dag_edges()
+    else:
+        rules = rdfs_plus_rules()
+        facts = kg_facts() + [f for b in stream_batches() for f in b]
+    want = counting_engine("numpy", rules)
+    want.insert_facts(facts)
+    want.infer()
+
+    inside = []
+    calls = {"join_pairs": 0, "match_rows": 0}
+
+    def spy(cls, name):
+        orig = getattr(cls, name)
+
+        def wrapped(self, *args, **kwargs):
+            if name in calls and inside:
+                calls[name] += 1
+            if name != "_apply_counts":
+                return orig(self, *args, **kwargs)
+            inside.append(True)
+            try:
+                return orig(self, *args, **kwargs)
+            finally:
+                inside.pop()
+        monkeypatch.setattr(cls, name, wrapped)
+
+    spy(HiperfactEngine, "_apply_counts")
+    spy(JaxOps, "join_pairs")
+    spy(JaxOps, "match_rows")
+    got = counting_engine("jax-interpret", rules)
+    got.insert_facts(facts)
+    got.infer()
+    assert decoded_fact_set(got) == decoded_fact_set(want)
+    assert calls["join_pairs"] == 0 and calls["match_rows"] > 0, calls
+
+
+def alive_support(engine):
+    """Support and assertion of every alive fact, by decoded triple."""
+    s = engine.store.strings
+    return {(ftype, s.lookup_id(int(t.ids[r])), s.lookup_id(int(t.attrs[r])),
+             int(t.vals[r])): (int(t.support[r]), bool(t.asserted[r]))
+            for ftype, t in engine.store.tables.items()
+            for r in np.flatnonzero(t.alive)}
+
+
+@pytest.mark.parametrize("ruleset", ["tc", "two-hop"])
+def test_reasserted_facts_match_their_newest_row(ruleset):
+    """Retracting a base edge kills derived rows; re-asserting it brings
+    the same triples back as new rows.  At most one copy of a triple is
+    alive and it is the newest, so ``_match_rows`` (the last equal row,
+    kept if alive) finds exactly the alive copy; support counts equal
+    the numpy engine's."""
+    from repro.core.engine import _match_rows
+    rules, out = ((TC_RULES, "path") if ruleset == "tc"
+                  else (TWO_HOP_RULES, "hop2"))
+    gone = CHAIN[2]
+    engines = {}
+    for backend in ("numpy", "jax-interpret"):
+        e = counting_engine(backend, rules)
+        e.insert_facts(dag_edges() + CHAIN)
+        e.infer()
+        n_before = e.store.tables[out].n
+        e.delete_facts([gone])
+        e.infer()
+        assert e.store.tables[out].n_dead > 0
+        e.insert_facts([gone])
+        e.infer()
+        assert e.store.tables[out].n > n_before
+        engines[backend] = e
+    assert alive_support(engines["jax-interpret"]) == alive_support(
+        engines["numpy"])
+
+    for e in engines.values():
+        copies = 0
+        for t in e.store.tables.values():
+            newest, alive_row = {}, {}
+            triples = list(zip(t.ids.tolist(), t.attrs.tolist(),
+                               t.vals.tolist()))
+            for r, k in enumerate(triples):
+                newest[k] = r
+                if t.alive[r]:
+                    assert k not in alive_row, "two alive copies"
+                    alive_row[k] = r
+            assert all(newest[k] == r for k, r in alive_row.items())
+            copies += len(triples) - len(newest)
+            rowof = _match_rows(t, t.ids, t.attrs, t.vals, e.ops,
+                                e._pk_memo)
+            np.testing.assert_array_equal(
+                rowof, [alive_row.get(k, -1) for k in triples])
+        assert copies > 0  # some triple has a dead older copy

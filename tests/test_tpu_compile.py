@@ -202,3 +202,23 @@ def test_batch_probe_compiles(on_tpu):
                                       use_pallas=pallas, interpret=False),
                  _i64(on_tpu), _i64(on_tpu, ()), _i64(on_tpu, (4096,)))
     _assert_route(c, "probe", jnp.int64)
+
+
+def test_write_side_row_lookup_compiles(on_tpu):
+    """The lookup of a batch's rows in a table's sorted (key, val) rows
+    (``JaxOps.match_rows``, and the set path's ``fresh_pairs``): XLA
+    programs, no kernel, whose code stays small — it sits in device
+    memory for every shape.  The rows are sorted by the shared key sort
+    (``test_sort_kv_compiles``) after ``pad_pairs``."""
+    from repro.backend.jax_ops import _jitted
+    jt = _jitted()
+    probe = _i64(on_tpu, (1 << 15,))
+    one = _i64(on_tpu, ())
+    c = _compile(jt["pad_pairs"], _i64(on_tpu), _i64(on_tpu), one)
+    assert "tpu_custom_call" not in c.as_text()
+    for c in (_compile(jt["match_pairs"], _i64(on_tpu), _i64(on_tpu),
+                       _spec(on_tpu, jnp.int32), one, probe, probe),
+              _compile(jt["fresh_pairs"], _i64(on_tpu), _i64(on_tpu), one,
+                       probe, probe)):
+        assert "tpu_custom_call" not in c.as_text()
+        assert c.memory_analysis().generated_code_size_in_bytes < 4 << 20

@@ -116,6 +116,38 @@ def test_materialization_spans(backend, tmp_path):
         assert d2h == []
 
 
+@pytest.mark.parametrize("backend", ["numpy", "jax-interpret"])
+def test_counting_write_spans_count_lookups(backend, tmp_path, monkeypatch):
+    """The counting path's ``hf.write`` spans carry ``match_rows`` (rows
+    looked up on the device) and ``match_host`` (on the host); together
+    they are the rows ``_apply_counts`` looked up in a table."""
+    import jax
+
+    looked_up = []
+    apply_counts = HiperfactEngine._apply_counts
+
+    def spy(self, ftype, ids, *rest):
+        if self.store.table(ftype).n:
+            looked_up.append(len(ids))
+        return apply_counts(self, ftype, ids, *rest)
+
+    monkeypatch.setattr(HiperfactEngine, "_apply_counts", spy)
+    e = engine(backend)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        e.insert_facts(kg_facts())
+        e.infer()
+    finally:
+        jax.profiler.stop_trace()
+    counted = [a for n, a in hf_events(str(tmp_path))
+               if n == "hf.write" and "match_rows" in a]
+    assert counted and all("match_host" in a for a in counted)
+    device = sum(a["match_rows"] for a in counted)
+    host = sum(a["match_host"] for a in counted)
+    assert device + host == sum(looked_up) > 0
+    assert (host if backend == "numpy" else device) == sum(looked_up)
+
+
 def test_span_costs_little_without_a_profiler():
     """With no profiler running a span is a cheap no-op context."""
     import time
