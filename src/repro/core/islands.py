@@ -615,7 +615,8 @@ def _join_step(store: FactStore, st: CondStats, acc: Bindings | None,
                     acc = _apply_test(store, acc, t, vt, ops, pipeline)
             else:
                 still.append((t, vt))
-        sp.set_metadata(rows=stats.get("rows_considered", 0) - before)
+        sp.set_metadata(rows=stats.get("rows_considered", 0) - before,
+                        rows_out=acc.n)
     return acc, still
 
 

@@ -8,6 +8,12 @@ import sys
 
 import pytest
 
+# the benchmark's plain reference, generators and configurations
+# (``bench/``) are what some tests compare the engine with
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
     """Run a python snippet in a subprocess with N host devices."""

@@ -3,15 +3,18 @@
 A small RDFS-Plus materialization runs under ``jax.profiler``: every
 span the closure path reaches shows up with its arguments, the transfer
 spans account for every host<->device byte the backend counts, and
-tracing leaves the fact set unchanged.
+tracing leaves the fact set unchanged.  Small tc and sg closures check
+the ``rows_out`` of the join spans.
 """
 
 import dataclasses
 import glob
+import json
 import os
 
 import pytest
 
+from bench import harness
 from repro.core import EngineConfig, Fact, HiperfactEngine
 from repro.core.rulesets import rdfs_plus_rules
 from repro.tracing import SPANS
@@ -159,3 +162,76 @@ def test_span_costs_little_without_a_profiler():
         with span("hf.join", rows=i) as sp:
             sp.set_metadata(rows=i)
     assert (time.perf_counter() - t0) / n < 50e-6
+
+
+def graph_engine(name, backend, nodes):
+    """A fresh engine of the benchmark configuration ``name`` (``orb-tc``
+    or ``orb-sg``) on ``backend``, and the base facts of its
+    generator's ``nodes``-node graph of out-degree 5."""
+    with open(os.path.join(harness.ROOT, "bench", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    cfg["scale"] = {"nodes": nodes, "edges": 5 * nodes}
+    cfg["engine"]["backend"] = backend
+    ds = harness.generator(cfg).generate(cfg, 3)
+    return harness.make_engine(cfg), ds.fact_objects()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax-interpret"])
+@pytest.mark.parametrize("name", ["orb-tc", "orb-sg"])
+def test_join_spans_carry_rows_out(name, backend, tmp_path, monkeypatch):
+    """Each ``hf.join`` span's ``rows_out`` is the row count of the
+    binding table its step returned, read from a count the host already
+    holds: the transfers of a closure are the same traced and not."""
+    import jax
+
+    from repro.core import islands
+
+    returned = []
+    join_step = islands._join_step
+
+    def spy(*args, **kw):
+        acc, still = join_step(*args, **kw)
+        returned.append(acc.n)
+        return acc, still
+
+    def closure(trace_dir=None):
+        e, facts = graph_engine(name, backend, 30)
+        counter = getattr(e.ops, "transfers", None)
+        if counter is not None:
+            e.ops.cache.clear()
+        before = counter.snapshot() if counter is not None else None
+        if trace_dir:
+            jax.profiler.start_trace(trace_dir)
+        try:
+            e.insert_facts(facts)
+            e.infer()
+        finally:
+            if trace_dir:
+                jax.profiler.stop_trace()
+        moved = counter.delta(before) if counter is not None else None
+        return e, moved
+
+    _, untraced = closure()
+    monkeypatch.setattr(islands, "_join_step", spy)
+    e, traced = closure(str(tmp_path))
+    assert traced == untraced
+    rows_out = [a["rows_out"] for n, a in hf_events(str(tmp_path))
+                if n == "hf.join"]
+    assert sorted(rows_out) == sorted(returned) and sum(rows_out) > 0
+
+    # one evaluation of the rule with most conditions: its first step
+    # looks a condition up, each later step joins one in
+    rule = max(e.rules, key=lambda r: len(r.conditions))
+    returned.clear()
+    trace_dir = str(tmp_path / "rule")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        out = islands.evaluate_rule(e.store, rule, ops=e.ops)
+    finally:
+        jax.profiler.stop_trace()
+    rows_out = [a["rows_out"] for n, a in hf_events(trace_dir)
+                if n == "hf.join"]
+    assert len(rows_out) == len(rule.conditions) == (
+        3 if name == "orb-sg" else 2)
+    assert rows_out == returned and rows_out[-1] == out.n > 0
