@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels.mergejoin.ops import expand_runs
 from repro.kernels.prefix import prefix_sum
 
 SENTINEL = jnp.iinfo(jnp.int64).max
@@ -99,21 +100,15 @@ def join_expand_bounded(
 
     ``l_key`` (sentinel-padded) probes ``r_sorted_key`` (sorted, padded);
     emits up to ``out_cap`` (l_payload, r_payload) pairs + overflow count.
-    The expansion is the searchsorted-on-prefix-sums trick: pure index
-    arithmetic, no data-dependent shapes.
+    The expansion is the merge join's (``mergejoin.ops.expand_runs``):
+    pure index arithmetic, no data-dependent shapes.
     """
     l_valid = l_key != SENTINEL
     lo = jnp.searchsorted(r_sorted_key, l_key, side="left")
     hi = jnp.searchsorted(r_sorted_key, l_key, side="right")
-    counts = jnp.where(l_valid, hi - lo, 0)
-    starts = prefix_sum(counts) - counts
-    total = jnp.sum(counts)
-    out_idx = jnp.arange(out_cap)
-    row = jnp.clip(jnp.searchsorted(starts, out_idx, side="right") - 1,
-                   0, l_key.shape[0] - 1)
-    within = out_idx - starts[row]
-    ok = (out_idx < total) & (within < counts[row])
-    r_idx = jnp.clip(lo[row] + within, 0, r_sorted_key.shape[0] - 1)
+    row, pos, ok, total = expand_runs(lo, jnp.where(l_valid, hi - lo, 0),
+                                      out_cap)
+    r_idx = jnp.clip(pos, 0, r_sorted_key.shape[0] - 1)
     out_l = jnp.where(ok, l_payload[row], SENTINEL)
     out_r = jnp.where(ok, r_payload[r_idx], SENTINEL)
     overflow = jnp.maximum(total - out_cap, 0)

@@ -1,8 +1,10 @@
 """jit'd sort-merge join built on the Pallas probe kernel.
 
 ``merge_join_bounded`` is the fully-jittable fixed-capacity join used by
-the distributed engine; the expansion of (lo, hi) runs into pairs is the
-searchsorted-on-prefix-sums trick (pure index arithmetic).
+the distributed engine.  ``expand_runs`` expands the (lo, hi) runs into
+pairs: each left row with candidates marks the output lane where its run
+starts (one scatter over the left lanes), and a running max of the marks
+(``prefix_max``, a streaming scan) gives every output lane its left row.
 
 ``merge_join_gather_bounded`` is the fused device-pipeline form: the same
 probe + expansion, but candidate pairs are refined (multi-key / hash
@@ -22,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.mergejoin.mergejoin import probe_sorted
-from repro.kernels.prefix import prefix_sum
+from repro.kernels.prefix import prefix_max, prefix_sum
 from repro.kernels.routing import route
 from repro.kernels.sortmerge.ops import device_sort_kv
 
@@ -47,6 +49,38 @@ def _widen_with_lanes(keys):
     return keys, jnp.arange(keys.shape[0], dtype=jnp.int32)
 
 
+def expand_runs(lo, counts, out_cap: int):
+    """Expand runs of candidates into ``out_cap`` output lanes.
+
+    Left row ``r`` owns ``counts[r]`` candidates at sorted right
+    positions ``lo[r] + j``; they take output lanes ``starts[r] + j``
+    with ``starts`` the exclusive prefix sum of ``counts``.  Returns
+    ``(row, pos, valid, total)``: each lane's left row and sorted right
+    position (int32), ``valid`` on the ``total`` candidate lanes (a
+    prefix), and ``total`` itself (may exceed ``out_cap``).
+
+    Each row with candidates writes its id at the lane where its run
+    starts (starts of such rows are distinct, so one dropping scatter
+    over the left lanes), and a running max of those marks carries it
+    over the rest of the run: on the valid lanes that is the row a
+    binary search of ``starts`` finds, at streaming cost per lane.
+    Lanes past ``total`` hold some row and position; callers mask them.
+    """
+    counts = counts.astype(jnp.int64)
+    starts = prefix_sum(counts) - counts
+    total = jnp.sum(counts)
+    # a start at or past out_cap owns no lane; clipping keeps the rest
+    # exact and everything within int32
+    first = jnp.minimum(starts, out_cap).astype(jnp.int32)
+    tgt = jnp.where(counts > 0, first, out_cap)
+    marks = jnp.zeros(out_cap, jnp.int32).at[tgt].set(
+        jnp.arange(counts.shape[0], dtype=jnp.int32), mode="drop")
+    row = prefix_max(marks)
+    out_idx = jnp.arange(out_cap, dtype=jnp.int32)
+    pos = out_idx + (lo.astype(jnp.int32) - first)[row]
+    return row, pos, out_idx < total, total
+
+
 @functools.partial(jax.jit,
                    static_argnames=("out_cap", "block", "pallas",
                                     "interpret"))
@@ -60,16 +94,8 @@ def _expand_pairs(l_keys, r_sorted, r_perm, out_cap: int, block: int,
     else:
         lo = jnp.searchsorted(r_sorted, l_keys, side="left").astype(jnp.int32)
         hi = jnp.searchsorted(r_sorted, l_keys, side="right").astype(jnp.int32)
-    counts = (hi - lo).astype(jnp.int64)
-    starts = prefix_sum(counts) - counts
-    total = jnp.sum(counts)
-    out_idx = jnp.arange(out_cap, dtype=jnp.int64)
-    row = jnp.clip(jnp.searchsorted(starts, out_idx, side="right") - 1,
-                   0, l_keys.shape[0] - 1)
-    within = out_idx - starts[row]
-    valid = (out_idx < total) & (within < counts[row])
-    ri = r_perm[jnp.clip(lo[row] + within.astype(jnp.int32), 0, m - 1)]
-    li = row.astype(jnp.int32)
+    li, pos, valid, total = expand_runs(lo, hi - lo, out_cap)
+    ri = r_perm[jnp.clip(pos, 0, m - 1)]
     return (jnp.where(valid, li, -1), jnp.where(valid, ri, -1), valid, total)
 
 
@@ -161,23 +187,17 @@ def _gather_pairs(l_keys, r_keys, lk, r_sorted, r_perm, hash_bad, n_l,
     # equals MAX; zeroing their counts makes that collision structurally
     # impossible (the remaining guard — a real *left* key equal to the
     # right pad sentinel MIN — is checked by the caller via handle bounds)
-    counts = jnp.where(real_l, (hi - lo).astype(jnp.int64), 0)
-    starts = prefix_sum(counts) - counts
-    total0 = jnp.sum(counts)
-    out_idx = jnp.arange(out_cap, dtype=jnp.int64)
-    row = jnp.clip(jnp.searchsorted(starts, out_idx, side="right") - 1,
-                   0, cap_l - 1)
-    within = out_idx - starts[row]
-    valid = out_idx < total0  # candidates are emitted as a prefix
-    li = row
-    ri = r_perm[jnp.clip(lo[row] + within.astype(jnp.int32),
-                         0, cap_r - 1)].astype(jnp.int64)
+    counts = jnp.where(real_l, hi - lo, 0)
+    # candidates are emitted as a prefix
+    li, pos, valid, total0 = expand_runs(lo, counts, out_cap)
+    ri = r_perm[jnp.clip(pos, 0, cap_r - 1)]
     ok = valid
     if hash_keys:
         ok = ok & (l_keys[li] == r_keys[ri])
     for vl, vr in zip(verify_l, verify_r):
         ok = ok & (vl[li] == vr[ri])
-    pos = prefix_sum(ok.astype(jnp.int64)) - 1
+    # int32 positions: out_cap, like every bucket, is below 2^31
+    pos = prefix_sum(ok.astype(jnp.int32)) - 1
     tgt = jnp.where(ok, pos, out_cap)
     l_out = tuple(jnp.zeros(out_cap, p.dtype).at[tgt].set(p[li],
                                                           mode="drop")
